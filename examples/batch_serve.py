@@ -24,6 +24,7 @@ import time
 sys.path.insert(0, "src")
 
 import repro  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import is_valid_coloring  # noqa: E402
 from repro.core.batch import color_batch_fused  # noqa: E402
 from repro.errors import Overloaded  # noqa: E402
@@ -33,6 +34,7 @@ from repro.obs.report import format_result, format_trace  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--batch", type=int, default=16)

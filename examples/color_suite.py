@@ -12,6 +12,7 @@ import numpy as np
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import color_data_driven, is_valid_coloring  # noqa: E402
 from repro.core.scheduling import phases, schedule_quality  # noqa: E402
 from repro.graphs import build_suite  # noqa: E402
@@ -36,6 +37,7 @@ def gauss_seidel_chromatic(g, colors, sweeps=2):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.1)
     args = ap.parse_args()

@@ -17,6 +17,7 @@ sys.path.insert(0, "src")
 
 import numpy as np  # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.d2 import (  # noqa: E402
     color_distance2,
     compress_jacobian_pattern,
@@ -28,6 +29,7 @@ from repro.graphs import grid2d, jacobian_band, jacobian_tall_skinny  # noqa: E4
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=4000)
     ap.add_argument("--band", type=int, default=3)

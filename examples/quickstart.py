@@ -12,6 +12,7 @@ import time
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import (  # noqa: E402
     color_data_driven,
     color_jp,
@@ -27,6 +28,7 @@ from repro.graphs.rmat import RMAT_G  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=20000)
     ap.add_argument("--degree", type=float, default=10.0)

@@ -25,6 +25,7 @@ import numpy as np
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import color_data_driven, is_valid_coloring  # noqa: E402
 from repro.dynamic import churn_delta  # noqa: E402
 from repro.graphs import build_graph  # noqa: E402
@@ -33,6 +34,7 @@ from repro.obs.report import format_metrics, format_result  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="G3_circuit")
     ap.add_argument("--scale", type=float, default=0.05)

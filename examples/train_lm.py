@@ -12,6 +12,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.launch.train import train_loop  # noqa: E402
 
@@ -26,6 +27,7 @@ PRESETS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=list(PRESETS), default="10m")
     ap.add_argument("--steps", type=int, default=300)

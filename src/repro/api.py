@@ -34,8 +34,8 @@ falls back to a per-graph loop otherwise.
 
 Backend selection (§15): ``color(g, backend="pallas")`` routes the rotated
 super-step through the fused Pallas kernel (``interpret=True`` off-TPU);
-``backend="jax"`` forces the pure-JAX engine, ``backend="auto"`` picks
-pallas on TPU only.  Colors are bit-identical across backends, so the knob
+``backend="jax"`` forces the pure-JAX engine, ``backend="auto"`` resolves
+to ``jax`` (``kernels/dispatch.py``).  Colors are bit-identical across backends, so the knob
 is pure performance policy; engines that cannot host the kernel (the
 multi-device sharded engine) fall back to pure-JAX automatically.
 

@@ -153,12 +153,22 @@ def gather_rows(adj: jax.Array, ids: jax.Array, sentinel: int | None = None) -> 
     return jnp.where((ids < n)[:, None], rows, fill)
 
 
+def take_tile(x: jax.Array, rows: jax.Array) -> jax.Array:
+    """``x[rows]`` for a ``(w, W)`` tile of in-bounds ids.
+
+    Same values as plain indexing, in the gather form the TPU compiler
+    builds fast: the default clamping gather of a 65,536 x 376 tile takes
+    it about 70 s on v5e, ``mode="fill"`` under a second.
+    """
+    return jnp.take(x, rows, mode="fill", fill_value=0)
+
+
 def ff_apply(adj, colors_ext, ids, kind: str, use_kernel: bool = False,
              rows=None):
     """FirstFit the worklist chunk ``ids`` and write colors (sentinel-safe)."""
     n = adj.shape[0]
     rows = gather_rows(adj, ids) if rows is None else rows
-    nc = colors_ext[rows]
+    nc = take_tile(colors_ext, rows)
     if use_kernel:
         from repro.kernels.firstfit.ops import firstfit_bitset_tpu
 
@@ -174,9 +184,9 @@ def cr_flags(adj, deg_ext, colors_ext, ids, heuristic: str,
     """Conflict flags for the worklist chunk ``ids`` (True = loses, recolor)."""
     rows = gather_rows(adj, ids) if rows is None else rows
     my_c = colors_ext[ids]
-    nc = colors_ext[rows]
+    nc = take_tile(colors_ext, rows)
     my_d = deg_ext[ids]
-    nd = deg_ext[rows]
+    nd = take_tile(deg_ext, rows)
     if use_kernel:
         from repro.kernels.conflict.ops import conflict_tpu
 
@@ -328,12 +338,12 @@ def ragged_superstep(rows_fn, deg_ext, colors_ext, wl, *,
         my_c = read[ids]
         my_d = deg_ext[ids]
         if pack_degrees and not use_kernel:
-            tile = (read + (deg_ext << 16))[rows]
+            tile = take_tile(read + (deg_ext << 16), rows)
             nc = tile & jnp.int32(0xFFFF)
             nd = tile >> 16
         else:
-            nc = read[rows]
-            nd = deg_ext[rows]
+            nc = take_tile(read, rows)
+            nd = take_tile(deg_ext, rows)
         if use_kernel:
             from repro.kernels.superstep.ops import superstep_tpu
 
@@ -1060,8 +1070,9 @@ def color_data_driven(
     ``backend`` picks the super-step implementation (DESIGN.md §15):
     ``"pallas"`` routes every degree-class tile through the fused Pallas
     kernel (``kernels/superstep``; ``interpret=True`` off-TPU), ``"jax"``
-    forces the pure-JAX formulation, ``"auto"`` picks pallas on TPU only,
-    and ``None`` defers to the legacy ``use_kernel`` knob.  Colors are
+    forces the pure-JAX formulation, ``"auto"`` resolves to ``"jax"``
+    (``kernels/dispatch.py``), and ``None`` defers to the legacy
+    ``use_kernel`` knob.  Colors are
     bit-identical across backends (tested in ``tests/test_differential.py``);
     the multi-device sharded engine always runs pure-JAX (automatic
     fallback — its ``shard_map`` body cannot host the kernel).

@@ -66,15 +66,6 @@ from repro.obs.trace import assemble_trace, empty_trace, resolve_trace_cap
 __all__ = ["ShardRows", "color_distributed", "run_sharded_engine"]
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):  # jax >= 0.4.35 top-level export
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 class ShardRows:
     """Per-shard CSR row provider over GLOBAL vertex ids (§13).
 
@@ -213,8 +204,8 @@ def _build_step(mesh, *, provider_kind: str, n: int, n_loc: int,
                 P("d", None)) + tuple(P("d", None) for _ in range(K))
     out_specs = (P("d", None), P("d", None), P("d", None), P("d")) + tuple(
         P("d", None) for _ in range(K))
-    return jax.jit(_shard_map(step, mesh, in_specs=in_specs,
-                              out_specs=out_specs))
+    return jax.jit(jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs))
 
 
 def _get_step(mesh, devices, **cfg):

@@ -26,19 +26,20 @@ def conflict_kernel(me_ref, nid_ref, nc_ref, nd_ref, out_ref, *, heuristic: str)
     nc = nc_ref[...]                # (bn, W) neighbor colors (0 in pads)
     nd = nd_ref[...]                # (bn, W) neighbor degrees (0 in pads)
 
-    my_id = me[:, COL_ID][:, None]
-    my_c = me[:, COL_COLOR][:, None]
-    my_d = me[:, COL_DEG][:, None]
+    my_id = me[:, COL_ID:COL_ID + 1]
+    my_c = me[:, COL_COLOR:COL_COLOR + 1]
+    my_d = me[:, COL_DEG:COL_DEG + 1]
 
     same = (nc == my_c) & (my_c > 0)
     if heuristic == "id":
         lose_lane = same & (my_id < nid)
     else:  # degree: larger degree keeps; tie -> smaller id keeps
         lose_lane = same & ((nd > my_d) | ((nd == my_d) & (nid < my_id)))
-    out_ref[...] = jnp.any(lose_lane, axis=1).astype(jnp.int32)
+    out_ref[...] = jnp.any(lose_lane, axis=1, keepdims=True).astype(jnp.int32)
 
 
 def conflict_pallas_call(w: int, W: int, block_n: int, heuristic: str, interpret: bool):
+    """Build the conflict-detect pallas_call for a (w, W) tile -> (w, 1)."""
     grid = (pl.cdiv(w, block_n),)
     row_spec = pl.BlockSpec((block_n, W), lambda i: (i, 0))
     return pl.pallas_call(
@@ -50,7 +51,7 @@ def conflict_pallas_call(w: int, W: int, block_n: int, heuristic: str, interpret
             row_spec,
             row_spec,
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.int32),
+        out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((w, 1), jnp.int32),
         interpret=interpret,
     )

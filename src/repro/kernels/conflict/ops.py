@@ -22,7 +22,7 @@ def _pick_block_n(w: int, W: int) -> int:
 def _run(me, nid, nc, nd, *, heuristic, block_n, interpret):
     return conflict_pallas_call(
         me.shape[0], nid.shape[1], block_n, heuristic, interpret
-    )(me, nid, nc, nd)
+    )(me, nid, nc, nd)[:, 0]
 
 
 def conflict_tpu(

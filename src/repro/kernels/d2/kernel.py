@@ -8,11 +8,8 @@ combined forbidden set register-resident instead of materializing the
 ``(w, W1 + W2)`` concatenation in HBM, which is the whole point at two-hop
 widths (W2 grows like W²).
 
-Find-first-set is computed structurally exactly as in
-``kernels/firstfit/kernel.py``: expand each word against a 32-lane bit
-iota, mask positions beyond the greedy bound W1+W2+1, min-reduce — shifts,
-compares and a min only, the friendliest Mosaic lowering (no gather, no
-popcount).
+Both tiles accumulate, and find-first-set runs, through the shared helpers
+in ``kernels/bitset.py``, with the greedy bound W1+W2+1.
 """
 from __future__ import annotations
 
@@ -20,25 +17,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.kernels.bitset import first_free, forbidden_words, nwords_for
+
 __all__ = ["d2_firstfit_kernel", "d2_firstfit_pallas_call"]
-
-
-def _accumulate_tile(nc, words, word_iota):
-    """OR the forbidden bits of one neighbor-color tile into ``words``."""
-    idx = nc - 1                      # bit position of each forbidden color
-    valid = idx >= 0
-    word_of = jnp.where(valid, idx >> 5, -1)
-    bit = (jnp.where(valid, idx, 0) & 31).astype(jnp.uint32)
-    bits = jnp.where(valid, jnp.uint32(1) << bit, jnp.uint32(0))
-
-    def body(d, words):
-        hit = word_iota == word_of[:, d][:, None]
-        return words | jnp.where(hit, bits[:, d][:, None], jnp.uint32(0))
-
-    return lax.fori_loop(0, nc.shape[1], body, words)
 
 
 def d2_firstfit_kernel(nc1_ref, nc2_ref, out_ref, *, nwords: int):
@@ -47,37 +30,23 @@ def d2_firstfit_kernel(nc1_ref, nc2_ref, out_ref, *, nwords: int):
     block_n = nc1.shape[0]
     bound = nc1.shape[1] + nc2.shape[1]  # colors 1..bound can be forbidden
 
-    word_iota = lax.broadcasted_iota(jnp.int32, (block_n, nwords), 1)
     words = jnp.zeros((block_n, nwords), jnp.uint32)
-    words = _accumulate_tile(nc1, words, word_iota)
-    words = _accumulate_tile(nc2, words, word_iota)
-
-    # find-first-set: min over (word, bit) of free positions <= bound
-    free = ~words                                              # (bn, nwords)
-    bitpos = lax.broadcasted_iota(jnp.uint32, (block_n, nwords, 32), 2)
-    is_free = ((free[:, :, None] >> bitpos) & jnp.uint32(1)) == jnp.uint32(1)
-    pos = (
-        lax.broadcasted_iota(jnp.int32, (block_n, nwords, 32), 1) * 32
-        + bitpos.astype(jnp.int32)
-    )
-    big = jnp.int32(bound + 2)
-    pos = jnp.where(is_free & (pos <= bound), pos, big)
-    out_ref[...] = jnp.min(pos, axis=(1, 2)).astype(jnp.int32) + 1
+    words = forbidden_words(nc1, words)
+    words = forbidden_words(nc2, words)
+    out_ref[...] = first_free(words, bound)
 
 
 def d2_firstfit_pallas_call(w: int, W1: int, W2: int, block_n: int,
                             interpret: bool):
-    """Build the pallas_call for (w, W1) + (w, W2) neighbor-color tiles."""
-    nwords = (W1 + W2 + 1 + 31) // 32
-    grid = (pl.cdiv(w, block_n),)
+    """Build the pallas_call for (w, W1) + (w, W2) color tiles -> (w, 1)."""
     return pl.pallas_call(
-        functools.partial(d2_firstfit_kernel, nwords=nwords),
-        grid=grid,
+        functools.partial(d2_firstfit_kernel, nwords=nwords_for(W1 + W2)),
+        grid=(pl.cdiv(w, block_n),),
         in_specs=[
             pl.BlockSpec((block_n, W1), lambda i: (i, 0)),
             pl.BlockSpec((block_n, W2), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.int32),
+        out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((w, 1), jnp.int32),
         interpret=interpret,
     )

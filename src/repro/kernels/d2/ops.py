@@ -23,7 +23,7 @@ def _pick_block_n(w: int, W1: int, W2: int) -> int:
 def _run(nc1, nc2, *, block_n: int, interpret: bool):
     return d2_firstfit_pallas_call(
         nc1.shape[0], nc1.shape[1], nc2.shape[1], block_n, interpret
-    )(nc1, nc2)
+    )(nc1, nc2)[:, 0]
 
 
 def d2_firstfit_bitset_tpu(

@@ -14,17 +14,19 @@ place that policy lives:
 * ``backend="pallas"`` — force the gathered-tile kernel path.  On non-TPU
   backends the kernels run in ``interpret=True`` mode (see
   ``kernels/superstep/ops.py``), slow but bit-identical — which is what
-  the differential test matrix runs in CI.
+  the differential test matrix runs in CI.  On a TPU they compile
+  (``tests/test_tpu_compile.py``).
 * ``backend="pallas-csr"`` — force the CSR-resident fused kernel path
   (DESIGN.md §18): the kernel gathers straight from the DeviceCSR arrays,
   no materialized ``(w, W)`` tile in HBM.  Engines or configurations that
   can't feed it CSR arrays (dense batch layouts, multi-chunk classes,
   packed-word overflow) fall back to the gathered kernel — bit-identical,
-  so the fallback is invisible except in wall-clock.
-* ``backend="auto"`` — ``pallas-csr`` when the default JAX backend is a
-  TPU, ``jax`` otherwise (interpret mode is a debugging tool, not a fast
-  path); the legacy ``use_kernel=True`` knob keeps meaning the gathered
-  kernel.
+  so the fallback is invisible except in wall-clock.  Its kernels do not
+  compile for the TPU (they load from ``ANY``-space refs directly), so on
+  a TPU this backend is refused; it runs in interpret mode elsewhere.
+* ``backend="auto"`` — ``jax`` on every platform: no kernel has yet been
+  measured faster than the pure-JAX engine on the chip.  The legacy
+  ``use_kernel=True`` knob keeps meaning the gathered kernel.
 
 Engines that cannot host any kernel (the §13 multi-device sharded engine —
 ``shard_map`` bodies stay pure-JAX) treat both pallas backends as an
@@ -57,18 +59,19 @@ def resolve_backend(backend: str | None, use_kernel: bool = False) -> str:
         from repro.options import _DEPRECATION_MSG
 
         warnings.warn(_DEPRECATION_MSG, DeprecationWarning, stacklevel=3)
-    if backend is None:
+    if backend in (None, "auto"):
         return "pallas" if use_kernel else "jax"
-    if backend == "auto":
-        if use_kernel:
-            return "pallas"
-        return "pallas-csr" if jax.default_backend() == "tpu" else "jax"
     if backend == "jax":
         if use_kernel:
             raise ValueError(
                 "backend='jax' contradicts use_kernel=True; drop one of them "
                 "(backend='pallas' is the kernel path)")
         return "jax"
+    if backend == "pallas-csr" and jax.default_backend() == "tpu":
+        raise ValueError(
+            "backend='pallas-csr' does not compile for the TPU: its kernels "
+            "load from ANY-space refs, which Mosaic only reaches by DMA; use "
+            "backend='pallas' or 'jax'")
     if backend in ("pallas", "pallas-csr"):
         return backend
     raise ValueError(

@@ -6,8 +6,7 @@ paper's register-resident bitmask — built by a vectorized fori-loop over the
 padded neighbor lanes.  CUDA's ``__ffs`` has no TPU counterpart, so
 find-first-set is computed structurally: expand each word against a 32-lane
 bit iota, mask out positions beyond the greedy bound W+1, and take the min
-position — shifts, compares and a min-reduce only, all native VPU ops (no
-gather, no popcount — friendliest possible Mosaic lowering).
+position (``kernels/bitset.py``).
 
 VMEM working set per grid step: the (block_n, W) neighbor-color tile plus
 (block_n, nwords) bit words — ``block_n`` is chosen by ops.py so this stays
@@ -19,8 +18,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
+
+from repro.kernels.bitset import first_free, forbidden_words, nwords_for
 
 __all__ = ["firstfit_kernel", "firstfit_pallas_call"]
 
@@ -28,45 +28,17 @@ __all__ = ["firstfit_kernel", "firstfit_pallas_call"]
 def firstfit_kernel(nc_ref, out_ref, *, nwords: int):
     nc = nc_ref[...]  # (block_n, W) int32 neighbor colors; 0 = none
     block_n, W = nc.shape
-
-    idx = nc - 1                      # bit position of each forbidden color
-    valid = idx >= 0
-    word_of = jnp.where(valid, idx >> 5, -1)
-    bit = (jnp.where(valid, idx, 0) & 31).astype(jnp.uint32)
-    bits = jnp.where(valid, jnp.uint32(1) << bit, jnp.uint32(0))
-
-    word_iota = lax.broadcasted_iota(jnp.int32, (block_n, nwords), 1)
-
-    def accumulate(d, words):
-        hit = word_iota == word_of[:, d][:, None]
-        return words | jnp.where(hit, bits[:, d][:, None], jnp.uint32(0))
-
-    words = lax.fori_loop(
-        0, W, accumulate, jnp.zeros((block_n, nwords), jnp.uint32)
-    )
-
-    # find-first-set: min over (word, bit) of free positions <= W
-    free = ~words                                              # (bn, nwords)
-    bitpos = lax.broadcasted_iota(jnp.uint32, (block_n, nwords, 32), 2)
-    is_free = ((free[:, :, None] >> bitpos) & jnp.uint32(1)) == jnp.uint32(1)
-    pos = (
-        lax.broadcasted_iota(jnp.int32, (block_n, nwords, 32), 1) * 32
-        + bitpos.astype(jnp.int32)
-    )
-    big = jnp.int32(W + 2)
-    pos = jnp.where(is_free & (pos <= W), pos, big)
-    out_ref[...] = jnp.min(pos, axis=(1, 2)).astype(jnp.int32) + 1
+    words = forbidden_words(nc, jnp.zeros((block_n, nwords), jnp.uint32))
+    out_ref[...] = first_free(words, W)
 
 
 def firstfit_pallas_call(w: int, W: int, block_n: int, interpret: bool):
-    """Build the pallas_call for a (w, W) neighbor-color tile."""
-    nwords = (W + 1 + 31) // 32
-    grid = (pl.cdiv(w, block_n),)
+    """Build the pallas_call for a (w, W) neighbor-color tile -> (w, 1)."""
     return pl.pallas_call(
-        functools.partial(firstfit_kernel, nwords=nwords),
-        grid=grid,
+        functools.partial(firstfit_kernel, nwords=nwords_for(W)),
+        grid=(pl.cdiv(w, block_n),),
         in_specs=[pl.BlockSpec((block_n, W), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.int32),
+        out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((w, 1), jnp.int32),
         interpret=interpret,
     )

@@ -26,7 +26,8 @@ def _on_tpu() -> bool:
 
 @partial(jax.jit, static_argnames=("block_n", "interpret"))
 def _run(nc, *, block_n: int, interpret: bool):
-    return firstfit_pallas_call(nc.shape[0], nc.shape[1], block_n, interpret)(nc)
+    return firstfit_pallas_call(
+        nc.shape[0], nc.shape[1], block_n, interpret)(nc)[:, 0]
 
 
 def firstfit_bitset_tpu(

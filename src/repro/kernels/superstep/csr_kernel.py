@@ -26,9 +26,9 @@ Layout (``pltpu.PrefetchScalarGridSpec``):
 Bit-identity: lanes past a row's degree are masked to the sentinel ``n``
 (whose packed word is 0 → color 0, degree 0), which reproduces the exact
 inputs ``DeviceCSR.gather_rows`` + the packed pure-JAX gather would feed
-the gathered kernel; the conflict + FirstFit arithmetic below is copied
-verbatim from ``superstep_kernel``.  ``interpret=True`` keeps the kernel
-testable on CPU CI.
+the gathered kernel; the conflict rule below is ``superstep_kernel``'s and
+the bitset FirstFit is the shared ``kernels/bitset.py``.  ``interpret=True``
+keeps the kernel testable on CPU CI.
 
 The grid=1 sequential variant at the bottom fuses the §12 serial tail
 on-device: clear the worklist's colors, then FirstFit each vertex in the
@@ -47,6 +47,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.bitset import first_free, forbidden_words, nwords_for
 from repro.kernels.superstep.ops import _pick_block_n
 
 __all__ = [
@@ -97,32 +98,8 @@ def superstep_csr_kernel(wl_ref, starts_ref, col_ref, packed_ref,
     need = jnp.any(lose_lane, axis=1) | (my_c == 0)
 
     nc = jnp.where(same & ~lose_lane, 0, nc)
-    idx = nc - 1
-    valid = idx >= 0
-    word_of = jnp.where(valid, idx >> 5, -1)
-    bit = (jnp.where(valid, idx, 0) & 31).astype(jnp.uint32)
-    bits = jnp.where(valid, jnp.uint32(1) << bit, jnp.uint32(0))
-
-    word_iota = lax.broadcasted_iota(jnp.int32, (block_n, nwords), 1)
-
-    def accumulate(d, words):
-        hit = word_iota == word_of[:, d][:, None]
-        return words | jnp.where(hit, bits[:, d][:, None], jnp.uint32(0))
-
-    words = lax.fori_loop(
-        0, W, accumulate, jnp.zeros((block_n, nwords), jnp.uint32)
-    )
-
-    free = ~words
-    bitpos = lax.broadcasted_iota(jnp.uint32, (block_n, nwords, 32), 2)
-    is_free = ((free[:, :, None] >> bitpos) & jnp.uint32(1)) == jnp.uint32(1)
-    pos = (
-        lax.broadcasted_iota(jnp.int32, (block_n, nwords, 32), 1) * 32
-        + bitpos.astype(jnp.int32)
-    )
-    big = jnp.int32(W + 2)
-    pos = jnp.where(is_free & (pos <= W), pos, big)
-    ff = jnp.min(pos, axis=(1, 2)).astype(jnp.int32) + 1
+    words = forbidden_words(nc, jnp.zeros((block_n, nwords), jnp.uint32))
+    ff = first_free(words, W)[:, 0]
 
     newc_ref[...] = jnp.where(need, ff, my_c).astype(jnp.int32)
     need_ref[...] = need.astype(jnp.int32)
@@ -136,13 +113,13 @@ def superstep_csr_pallas_call(w: int, W: int, block_n: int, n: int,
     with sentinels) — scalar-prefetch reads have no out-of-bounds block
     padding, unlike dense BlockSpec operands.
     """
-    nwords = (W + 1 + 31) // 32
+    nwords = nwords_for(W)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # wl, starts
         grid=(w // block_n,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # col_padded
-            pl.BlockSpec(memory_space=pltpu.ANY),  # packed color|deg table
+            pl.BlockSpec(memory_space=pl.ANY),  # col_padded
+            pl.BlockSpec(memory_space=pl.ANY),  # packed color|deg table
         ],
         out_specs=(
             pl.BlockSpec((block_n,), lambda i, *_: (i,)),
@@ -263,10 +240,10 @@ def serial_tail_csr_pallas_call(T: int, W: int, n: int, interpret: bool):
         num_scalar_prefetch=3,  # wl, starts, degs
         grid=(1,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # col_padded
-            pl.BlockSpec(memory_space=pltpu.ANY),  # colors_ext (aliased)
+            pl.BlockSpec(memory_space=pl.ANY),  # col_padded
+            pl.BlockSpec(memory_space=pl.ANY),  # colors_ext (aliased)
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
     )
     return pl.pallas_call(
         functools.partial(serial_tail_csr_kernel, T=T, W=W, n=n),

@@ -9,7 +9,8 @@ worklist vertices over a single resident neighbor tile:
 * **FirstFit** — if it does not (or I am uncolored), the smallest permissible
   color from the same tile, via the §3.2 bitset: forbidden colors packed into
   uint32 words that live in VREGs for the whole kernel, find-first-set
-  computed structurally (bit-iota + min-reduce, no ``__ffs`` on TPU).
+  computed structurally (bit-iota + min-reduce, no ``__ffs`` on TPU); see
+  ``kernels/bitset.py``.
 
 The classic engine ran these as two kernels with two HBM round trips of the
 ``(w, W)`` neighbor tiles; here the tiles stream HBM->VMEM once and both
@@ -18,8 +19,9 @@ per iteration" contract (`core/coloring.py` provides the gather-level half).
 
 Layout matches the conflict kernel: per-row scalars packed as a
 ``(block_n, 3)`` int32 tile ``[id, color, degree]``; neighbor ids/colors/
-degrees as three ``(block_n, W)`` tiles.  Outputs are the new color per row
-and an int32 "needs re-verification" flag (1 where the row was recolored).
+degrees as three ``(block_n, W)`` tiles.  Outputs are ``(w, 1)`` columns:
+the new color per row and an int32 "needs re-verification" flag (1 where
+the row was recolored).
 """
 from __future__ import annotations
 
@@ -27,8 +29,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
+
+from repro.kernels.bitset import first_free, forbidden_words, nwords_for
 
 __all__ = ["superstep_kernel", "superstep_pallas_call",
            "COL_ID", "COL_COLOR", "COL_DEG"]
@@ -44,9 +47,9 @@ def superstep_kernel(me_ref, nid_ref, nc_ref, nd_ref, newc_ref, need_ref, *,
     nd = nd_ref[...]                # (bn, W) neighbor degrees (0 in pads)
     block_n, W = nc.shape
 
-    my_id = me[:, COL_ID][:, None]
-    my_c = me[:, COL_COLOR][:, None]
-    my_d = me[:, COL_DEG][:, None]
+    my_id = me[:, COL_ID:COL_ID + 1]
+    my_c = me[:, COL_COLOR:COL_COLOR + 1]
+    my_d = me[:, COL_DEG:COL_DEG + 1]
 
     # ---- phase 1: conflict detection on the current speculative colors ----
     same = (nc == my_c) & (my_c > 0)
@@ -54,51 +57,32 @@ def superstep_kernel(me_ref, nid_ref, nc_ref, nd_ref, newc_ref, need_ref, *,
         lose_lane = same & (my_id < nid)
     else:  # degree: larger degree keeps; tie -> smaller id keeps
         lose_lane = same & ((nd > my_d) | ((nd == my_d) & (nid < my_id)))
-    need = jnp.any(lose_lane, axis=1) | (me[:, COL_COLOR] == 0)
+    need = jnp.any(lose_lane, axis=1, keepdims=True) | (my_c == 0)
 
     # ---- phase 2: bitset FirstFit from the SAME tile (words stay in VREGs) --
     # same-color lanes I beat are provably recoloring too — refit as if they
     # were already cleared (the classic engine's clear-then-refit dynamics)
     nc = jnp.where(same & ~lose_lane, 0, nc)
-    idx = nc - 1                      # bit position of each forbidden color
-    valid = idx >= 0
-    word_of = jnp.where(valid, idx >> 5, -1)
-    bit = (jnp.where(valid, idx, 0) & 31).astype(jnp.uint32)
-    bits = jnp.where(valid, jnp.uint32(1) << bit, jnp.uint32(0))
+    words = forbidden_words(nc, jnp.zeros((block_n, nwords), jnp.uint32))
+    ff = first_free(words, W)
 
-    word_iota = lax.broadcasted_iota(jnp.int32, (block_n, nwords), 1)
-
-    def accumulate(d, words):
-        hit = word_iota == word_of[:, d][:, None]
-        return words | jnp.where(hit, bits[:, d][:, None], jnp.uint32(0))
-
-    words = lax.fori_loop(
-        0, W, accumulate, jnp.zeros((block_n, nwords), jnp.uint32)
-    )
-
-    free = ~words                                              # (bn, nwords)
-    bitpos = lax.broadcasted_iota(jnp.uint32, (block_n, nwords, 32), 2)
-    is_free = ((free[:, :, None] >> bitpos) & jnp.uint32(1)) == jnp.uint32(1)
-    pos = (
-        lax.broadcasted_iota(jnp.int32, (block_n, nwords, 32), 1) * 32
-        + bitpos.astype(jnp.int32)
-    )
-    big = jnp.int32(W + 2)
-    pos = jnp.where(is_free & (pos <= W), pos, big)
-    ff = jnp.min(pos, axis=(1, 2)).astype(jnp.int32) + 1
-
-    newc_ref[...] = jnp.where(need, ff, me[:, COL_COLOR]).astype(jnp.int32)
+    newc_ref[...] = jnp.where(need, ff, my_c).astype(jnp.int32)
     need_ref[...] = need.astype(jnp.int32)
 
 
 def superstep_pallas_call(w: int, W: int, block_n: int, heuristic: str,
                           interpret: bool):
-    """Build the fused super-step pallas_call for a (w, W) neighbor tile."""
-    nwords = (W + 1 + 31) // 32
+    """Build the fused super-step pallas_call for a (w, W) neighbor tile.
+
+    Outputs are ``(w, 1)`` columns: a rank-1 ``(block_n,)`` block is refused
+    by Mosaic unless ``block_n`` matches the 1-D HBM tiling (1024).
+    """
     grid = (pl.cdiv(w, block_n),)
     row_spec = pl.BlockSpec((block_n, W), lambda i: (i, 0))
+    col_spec = pl.BlockSpec((block_n, 1), lambda i: (i, 0))
     return pl.pallas_call(
-        functools.partial(superstep_kernel, nwords=nwords, heuristic=heuristic),
+        functools.partial(superstep_kernel, nwords=nwords_for(W),
+                          heuristic=heuristic),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, 3), lambda i: (i, 0)),
@@ -106,13 +90,10 @@ def superstep_pallas_call(w: int, W: int, block_n: int, heuristic: str,
             row_spec,
             row_spec,
         ],
-        out_specs=(
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ),
+        out_specs=(col_spec, col_spec),
         out_shape=(
-            jax.ShapeDtypeStruct((w,), jnp.int32),
-            jax.ShapeDtypeStruct((w,), jnp.int32),
+            jax.ShapeDtypeStruct((w, 1), jnp.int32),
+            jax.ShapeDtypeStruct((w, 1), jnp.int32),
         ),
         interpret=interpret,
     )

@@ -31,9 +31,10 @@ def _pick_block_n(w: int, W: int, *, tiles: int = 3) -> int:
 
 @partial(jax.jit, static_argnames=("heuristic", "block_n", "interpret"))
 def _run(me, nid, nc, nd, *, heuristic, block_n, interpret):
-    return superstep_pallas_call(
+    newc, need = superstep_pallas_call(
         me.shape[0], nid.shape[1], block_n, heuristic, interpret
     )(me, nid, nc, nd)
+    return newc[:, 0], need[:, 0]
 
 
 def superstep_tpu(

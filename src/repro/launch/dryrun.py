@@ -28,6 +28,7 @@ import traceback
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs import get_config, list_archs
 from repro.distributed.sharding import (
@@ -310,7 +311,8 @@ def main():
         shape = tuple(int(x) for x in args.mesh_shape.split("x"))
         axes = tuple(args.mesh_axes.split(",")) if args.mesh_axes else (
             ("data", "model") if len(shape) == 2 else ("pod", "data", "model"))
-        mesh_override = jax.make_mesh(shape, axes)
+        mesh_override = jax.make_mesh(
+            shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
     records = _load(args.out)
     done = {(r["arch"], r["shape"], r["mesh"]) for r in records if r.get("ok")}
