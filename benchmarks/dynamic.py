@@ -35,8 +35,7 @@ def _churn_once(name: str, scale: float, rounds: int = 4,
     ``trace=True`` (schema 6) opens the session with §16 tracing and adds
     ``rounds_detail`` — per round: frontier size, engine work, superstep
     count, tail-trigger step, and whether the recolor hit the jit cache —
-    plus a ``jit`` hits/misses section from ``session.metrics()``; the
-    return becomes ``(record, last_round_trace)``.
+    plus a ``jit`` hits/misses section from ``session.metrics()``.
     """
     from repro.core import color_data_driven
     from repro.dynamic import churn_delta, open_session
@@ -49,7 +48,6 @@ def _churn_once(name: str, scale: float, rounds: int = 4,
     t_inc, t_cold = [], []
     valid = True
     detail = []
-    last_trace = None
     prev_hits = 0
     for i in range(rounds):
         rem, add = churn_delta(session.graph, CHURN, rng)
@@ -69,13 +67,12 @@ def _churn_once(name: str, scale: float, rounds: int = 4,
             m = session.metrics()
             hit = m["engine_cache_hits"] > prev_hits
             prev_hits = m["engine_cache_hits"]
-            last_trace = inc.trace
             detail.append({
                 "round": i,
                 "frontier": int(dirty.size),
                 "work": int(inc.work_items),
-                "supersteps": int(last_trace.iterations),
-                "tail_step": last_trace.tail_step,
+                "supersteps": int(inc.trace.iterations),
+                "tail_step": inc.trace.tail_step,
                 "cache_hit": bool(hit),
             })
     rec = {
@@ -100,7 +97,7 @@ def _churn_once(name: str, scale: float, rounds: int = 4,
     rec["rounds_detail"] = detail
     rec["jit"] = {"hits": m["engine_cache_hits"],
                   "misses": m["engine_cache_misses"]}
-    return rec, last_trace
+    return rec
 
 
 def bench_dynamic_churn():
@@ -116,19 +113,10 @@ def bench_dynamic_churn():
 
 
 def bench_dynamic_json(scale: float, backend: str | None = None):
-    """The ``dynamic`` BENCH section (schema 6): churn records + traces.
-
-    Returns ``(records, runs)``: one churn record per suite graph (with
-    per-round detail and jit accounting) and the last-round recolor
-    ``RunTrace`` per graph for the Chrome-trace export.
-    """
-    records, runs = {}, {}
-    for name in CHURN_GRAPHS:
-        rec, rt = _churn_once(name, scale, backend=backend, trace=True)
-        records[name] = rec
-        if rt is not None:
-            runs[f"dynamic/{name}"] = rt
-    return records, runs
+    """The ``dynamic`` BENCH section (schema 6): one churn record per suite
+    graph, with per-round detail and jit accounting."""
+    return {name: _churn_once(name, scale, backend=backend, trace=True)
+            for name in CHURN_GRAPHS}
 
 
 DYNAMIC_BENCHES = (bench_dynamic_churn,)

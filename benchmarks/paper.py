@@ -9,8 +9,7 @@ This module IS wired into the harness (audited for PR §16): ``run.py``'s
 CSV matrix iterates ``ALL_BENCHES`` on every non ``--json-only`` run, and
 the weekly CI job (``--scale small`` without ``--json-only``) executes the
 full set.  Step-level telemetry for these runs lives in the schema-6 JSON
-documents (``trace`` sections + the ``_trace.json`` Chrome export), not in
-the CSV rows.
+documents (``trace`` sections), not in the CSV rows.
 """
 from __future__ import annotations
 

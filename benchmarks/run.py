@@ -49,11 +49,8 @@ UNTIMED traced call so the timed numbers stay on the untraced (bit-
 identical, zero-cost) path.  ``--engine dynamic`` records gain
 ``rounds_detail`` (per churn round: frontier, work, supersteps, tail step,
 jit cache hit) and a ``jit`` hits/misses section from
-``session.metrics()``.  Alongside the document a Chrome-trace
-(Perfetto-loadable) export of the same runs is written to
-``<JSON_PATH stem>_trace.json`` (so CI's ``BENCH_coloring*.json`` artifact
-glob picks it up); ``python -m repro.obs.report <either file>`` re-renders
-both.  ``benchmarks/check_regression.py`` gates the new sections: missing
+``session.metrics()``; ``python -m repro.obs.report <file>`` re-renders
+them.  ``benchmarks/check_regression.py`` gates the new sections: missing
 trace, superstep-count regressions, earlier tail triggers, broken row
 invariants, and dynamic jit-miss growth all fail CI.
 
@@ -142,7 +139,6 @@ def bench_coloring_json(path: str = JSON_PATH, engine: str = "ragged",
         "algorithms": {},
         "bipartite": {},
     }
-    chrome_runs = {}
     for alg in api.algorithms():
         if alg == "bipartite":  # needs a BipartiteGraph; measured below
             continue
@@ -179,7 +175,6 @@ def bench_coloring_json(path: str = JSON_PATH, engine: str = "ragged",
                 rt = api.color(g, algorithm=alg, trace=True, **opts).trace
                 if rt is not None:
                     rec["trace"] = rt.summary()
-                    chrome_runs[f"{alg}/{name}"] = rt
             per_graph[name] = rec
         doc["algorithms"][alg] = per_graph
     band = 2
@@ -198,24 +193,7 @@ def bench_coloring_json(path: str = JSON_PATH, engine: str = "ragged",
     with open(path, "w") as f:
         json.dump(doc, f, indent=2, sort_keys=True)
         f.write("\n")
-    if chrome_runs:
-        _write_chrome_trace(path, chrome_runs)
     return doc
-
-
-def _write_chrome_trace(json_path: str, runs: dict) -> str:
-    """Perfetto-loadable sibling of a BENCH document (schema 6).
-
-    Named ``<stem>_trace.json`` so CI's ``BENCH_coloring*.json`` artifact
-    glob uploads it alongside the document it mirrors.
-    """
-    from repro.obs.export import export_chrome_trace
-
-    stem = json_path[:-5] if json_path.endswith(".json") else json_path
-    trace_path = f"{stem}_trace.json"
-    export_chrome_trace(trace_path, runs)
-    print(f"# wrote {trace_path} ({len(runs)} traced runs)", file=sys.stderr)
-    return trace_path
 
 
 ENGINES = ("ragged", "padded", "classic", "sharded", "dynamic")
@@ -227,7 +205,7 @@ def bench_dynamic_json_doc(path: str = JSON_PATH,
     from benchmarks.dynamic import bench_dynamic_json
 
     json_scale = float(os.environ.get("REPRO_BENCH_JSON_SCALE", "0.02"))
-    records, runs = bench_dynamic_json(json_scale, backend=backend)
+    records = bench_dynamic_json(json_scale, backend=backend)
     doc = {
         "schema": 8,
         "scale": json_scale,
@@ -238,8 +216,6 @@ def bench_dynamic_json_doc(path: str = JSON_PATH,
     with open(path, "w") as f:
         json.dump(doc, f, indent=2, sort_keys=True)
         f.write("\n")
-    if runs:
-        _write_chrome_trace(path, runs)
     return doc
 
 

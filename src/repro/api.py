@@ -149,9 +149,17 @@ def color(graph: "CSRGraph", algorithm: "str | ColorOptions | None" = None,
     error.  Every escalation taken is recorded in
     ``result.degradations`` and emitted as ``guarantee_ladder`` obs spans.
     """
+    from repro.obs.spans import span
+
     o = _normalize(algorithm, options, opts)
     algorithm = o.algorithm or "data_driven"
     fn = get_algorithm(algorithm)
+    # the request's root span: every phase span of the call nests under it
+    with span("color", algorithm=algorithm, n=getattr(graph, "n", None)):
+        return _color(graph, algorithm, fn, o)
+
+
+def _color(graph, algorithm: str, fn: Callable, o: ColorOptions):
     engine_opts = o.engine_kwargs()
     pre = ()
     if o.validate_input is not None:

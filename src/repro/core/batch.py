@@ -51,7 +51,7 @@ from repro.core.coloring import (
     sgr_step,
 )
 from repro.core.csr import CSRGraph, next_pow2
-from repro.obs.spans import SpanRecorder, jit_span, span
+from repro.obs.spans import SpanRecorder, span
 from repro.obs.trace import (
     assemble_trace,
     empty_trace,
@@ -370,11 +370,7 @@ def color_batch_fused(
             dtype=np.int32,
         )
         pack = _packed_gather_ok(batch.width)
-        loop_key = ("batch", batch.B, batch.n_max, batch.width, heuristic,
-                    firstfit, use_kernel, tail_enabled, pack, max_iters,
-                    trace_cap)
-        with span("superstep_loop", mode="batched", B=batch.B), \
-                jit_span("batched_loop", loop_key):
+        with span("superstep_loop", mode="batched", B=batch.B):
             state = _run_batch(
                 batch.adj, batch.deg_ext, sizes, jnp.asarray(thrs_np),
                 jnp.int32(max_iters),
@@ -402,6 +398,7 @@ def color_batch_fused(
                     jnp.asarray(run_tail), jnp.asarray(stalled),
                     jnp.asarray(sizes_np), kind=firstfit,
                 )
+                colors_ext.block_until_ready()  # the readback waits anyway
             iters_b += run_tail
             work_b += np.where(stalled, sizes_np,
                                np.where(run_tail, counts, 0))

@@ -51,7 +51,7 @@ from repro.api import register
 from repro.core.csr import CSRGraph, DeviceCSR, auto_tile_thresholds, next_pow2
 from repro.core.firstfit import FF_FUNCS
 from repro.core.heuristics import conflict_lose_flags, conflict_lose_lanes
-from repro.obs.spans import SpanRecorder, jit_span, span
+from repro.obs.spans import SpanRecorder, span
 from repro.obs.trace import (
     assemble_trace,
     empty_trace,
@@ -681,19 +681,16 @@ def run_ragged_engine(
                     padded += cap * acc_widths[k]
                     cells_k[k] += cap * acc_widths[k]
                     step_cells += cap * acc_widths[k]
-            shapes = tuple(int(s.shape[0]) for s in sliced)
-            with jit_span("superstep", ("tiled", type(provider).__name__,
-                                        shapes, tuple(tile_widths), heuristic,
-                                        kind, use_kernel, tuple(chunk_l),
-                                        pack_degrees, n)):
+            # the count reads wait for the device: the span holds the step
+            with span("superstep", step=iters, live=total, cells=step_cells):
                 colors_ext, new_wls, cnts = provider_tiled_superstep(
                     provider, deg_ext, colors_ext, tuple(sliced),
                     widths=tuple(tile_widths), heuristic=heuristic, kind=kind,
                     use_kernel=use_kernel, chunks=tuple(chunk_l),
                     pack_degrees=pack_degrees,
                 )
+                counts = [int(c) for c in cnts]
             wls = list(new_wls)
-            counts = [int(c) for c in cnts]
             iters += 1
             new_total = sum(counts)
             if trace_cap:
@@ -713,11 +710,15 @@ def run_ragged_engine(
             )
             tail_np = np.full(min(next_pow2(total), n), n, np.int32)
             tail_np[:total] = live
-        with span("serial_tail", live=total, stalled=stalled):
+        with span("serial_tail", live=total, stalled=stalled,
+                  vertices=int(tail_np.shape[0])):
             tail_wl = order_tail(jnp.asarray(tail_np), deg_ext)
             colors_ext = _dispatch_tail(provider, colors_ext, tail_wl,
                                         kind=kind, use_kernel=use_kernel,
                                         width=tail_width)
+            # the readback below waits for this buffer anyway: the span
+            # ends when the device is done with the tail
+            colors_ext.block_until_ready()
         work += n if stalled and stall_serializes_all else total
         tail_cells = int(tail_wl.shape[0]) * tail_width
         padded += tail_cells
@@ -815,12 +816,7 @@ def _run_ragged_fused(
     wls0 = tuple(jnp.asarray(c) for c in classes)
     counts0 = tuple(jnp.int32(c) for c in counts_init)
     cells_per_step = sum(c * w for c, w in zip(caps, acc_widths))
-    loop_key = ("fused_spec", type(provider).__name__, tuple(caps),
-                tuple(tile_widths), heuristic, kind, use_kernel,
-                tuple(chunks), tail_enabled, max_iters, boot_iters,
-                pack_degrees, n, trace_cap)
-    with span("superstep_loop", mode="fused"), jit_span("fused_spec_loop",
-                                                        loop_key):
+    with span("superstep_loop", mode="fused"):
         out = _fused_spec_loop(
             provider, deg_ext, colors_ext, wls0, counts0,
             jnp.int32(tail_threshold),
@@ -849,15 +845,17 @@ def _run_ragged_fused(
     if total > 0 and iters < max_iters and tail_enabled:
         stalled = total > tail_threshold and bool(
             _stalled(iters, total, int(prev)))
-        with span("serial_tail", live=total, stalled=stalled):
+        with span("serial_tail", live=total, stalled=stalled) as sp:
             if stalled and stall_serializes_all:
                 tail_wl = order_tail(jnp.arange(n, dtype=jnp.int32), deg_ext)
             else:
                 combined = jnp.concatenate(list(wls)) if K > 1 else wls[0]
                 tail_wl = order_tail(combined, deg_ext)
+            sp.meta["vertices"] = int(tail_wl.shape[0])
             colors_ext = _dispatch_tail(provider, colors_ext, tail_wl,
                                         kind=kind, use_kernel=use_kernel,
                                         width=tail_width)
+            colors_ext.block_until_ready()  # the readback waits for it anyway
         work_items += n if stalled and stall_serializes_all else total
         tail_cells = int(tail_wl.shape[0]) * tail_width
         padded += tail_cells

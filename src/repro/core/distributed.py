@@ -60,7 +60,7 @@ from repro.core.coloring import (
 )
 from repro.core.csr import CSRGraph, DeviceCSR, PartitionedCSR, next_pow2
 from repro.core.heuristics import HEURISTICS
-from repro.obs.spans import SpanRecorder, jit_span, span
+from repro.obs.spans import SpanRecorder, span
 from repro.obs.trace import assemble_trace, empty_trace, resolve_trace_cap
 
 __all__ = ["ShardRows", "color_distributed", "run_sharded_engine"]
@@ -340,16 +340,15 @@ def run_sharded_engine(
             prev = total
             cap_s = min(next_pow2(max(int(scounts.max(initial=0)), 1)),
                         int(swl.shape[1]))
-            with jit_span("superstep", ("sharded_step", provider_kind, n, L,
-                                        ndev, tuple(tile_widths), heuristic,
-                                        kind, pack_degrees, pack_halo,
-                                        cap_s)):
+            # the count reads wait for the devices: the span holds the step
+            with span("superstep", step=iters, live=total,
+                      cells=cells_per_step):
                 out = step(prov, start_dev, bmask_dev, deg_dev, view,
                            swl[:, :cap_s], *wls)
-            view, swl, counts_dev, scounts_dev = out[:4]
+                view, swl, counts_dev, scounts_dev = out[:4]
+                counts = np.asarray(counts_dev)
+                scounts = np.asarray(scounts_dev)
             wls = list(out[4:])
-            counts = np.asarray(counts_dev)
-            scounts = np.asarray(scounts_dev)
             # received per device: ndev × cap_s halo entries (padded lanes too)
             step_halo = halo_entry_bytes * ndev * cap_s
             halo_bytes += step_halo
@@ -382,6 +381,7 @@ def run_sharded_engine(
                 tail_wl = order_tail(jnp.asarray(flat), deg_ext_loc)
             colors_ext = provider_tail(tail_provider, colors_ext, tail_wl,
                                        kind=kind)
+            colors_ext.block_until_ready()  # the readback waits for it anyway
         work += n if stalled else total
         tail_cells = int(tail_wl.shape[0]) * tail_width
         padded += tail_cells

@@ -1,4 +1,4 @@
-"""Observability layer: trace rings, phase spans, exporters (DESIGN.md §16).
+"""Observability layer: trace rings, phase spans, reports (DESIGN.md §16).
 
 Three pieces, layered:
 
@@ -6,15 +6,19 @@ Three pieces, layered:
   engine attaches as ``ColoringResult.trace`` when called with
   ``trace=True`` (a STATIC knob: ``trace=False`` compiles the identical
   XLA program and stays bit-identical/zero-cost).
-* ``obs.spans``  — host-side monotonic-clock phase spans with
-  compile-vs-execute attribution per jit cache key.
-* ``obs.export`` / ``obs.report`` — Chrome-trace (Perfetto-loadable)
-  JSON export and the shared text reporter
+* ``obs.spans``  — host-side phase spans, always recorded into a bounded
+  process-wide ring, stamped on the profiler's clock (``time.time_ns()``)
+  and shown in profiler traces as ``repro.<name>`` annotations, with the
+  compiles and cache loads JAX reports counted per phase;
+  ``last_requests(k)`` reads the last ``k`` ``repro.color`` requests.
+* ``obs.report`` — the shared text reporter
   (``python -m repro.obs.report``).
+
+A profiler trace (``jax.profiler.trace``) is the timeline view: it holds
+the ``repro.*`` spans beside the device's programs and ops.
 """
-from .export import chrome_trace, export_chrome_trace
 from .report import format_metrics, format_result, format_spans, format_trace
-from .spans import SpanEvent, SpanRecorder, jit_span, recorder, span
+from .spans import SpanEvent, SpanRecorder, last_requests, recorder, span
 from .trace import (
     DEFAULT_TRACE_CAP,
     NF,
@@ -43,9 +47,7 @@ __all__ = [
     "SpanRecorder",
     "recorder",
     "span",
-    "jit_span",
-    "chrome_trace",
-    "export_chrome_trace",
+    "last_requests",
     "format_result",
     "format_trace",
     "format_spans",

@@ -4,12 +4,11 @@ The one reporting path shared by ``examples/`` and ``benchmarks/``:
 
 * ``format_result``   — one-line engine-run summary from a ``ColoringResult``
 * ``format_trace``    — per-super-step table from a ``RunTrace``
-* ``format_spans``    — phase table with the compile-vs-execute split
+* ``format_spans``    — phase table with the compiles and cache loads
 * ``format_metrics``  — aligned key/value block (``session.metrics()``)
 
-and a CLI that re-reports from files instead of rerunning anything::
+and a CLI that re-reports from a file instead of rerunning anything::
 
-    python -m repro.obs.report trace.json          # Chrome-trace export
     python -m repro.obs.report BENCH_coloring.json # BENCH schema >= 6 doc
 """
 from __future__ import annotations
@@ -74,12 +73,13 @@ def format_spans(spans) -> str:
         return "spans: (none recorded)"
     rec = SpanRecorder()
     rec.events = events
-    lines = [f"{'phase':<22} {'count':>5} {'total_ms':>10} {'compile_ms':>11}"]
+    lines = [f"{'phase':<22} {'count':>5} {'total_ms':>10} {'compiles':>8} "
+             f"{'loads':>6}"]
     for name, agg in sorted(rec.by_name().items(),
                             key=lambda kv: -kv[1]["seconds"]):
         lines.append(f"{name:<22} {agg['count']:>5} "
                      f"{agg['seconds'] * 1e3:>10.2f} "
-                     f"{agg['compile_seconds'] * 1e3:>11.2f}")
+                     f"{agg['compiles']:>8} {agg['cache_loads']:>6}")
     return "\n".join(lines)
 
 
@@ -92,14 +92,6 @@ def format_metrics(metrics: dict, title: str = "") -> str:
             v = f"{v:.4g}"
         lines.append(f"  {k:<{width}} : {v}")
     return "\n".join(lines)
-
-
-def _report_chrome(doc: dict, last: int | None) -> str:
-    out = []
-    for label, tdict in sorted(doc["otherData"].get("repro", {}).items()):
-        out.append(format_trace(RunTrace.from_dict(tdict), last=last))
-        out.append("")
-    return "\n".join(out).rstrip()
 
 
 def _report_bench(doc: dict, last: int | None) -> str:
@@ -150,17 +142,15 @@ def main(argv=None) -> int:
         del argv[i:i + 2]
     if len(argv) != 1:
         print("usage: python -m repro.obs.report [--last N] "
-              "<chrome_trace.json | BENCH_*.json>", file=sys.stderr)
+              "<BENCH_*.json>", file=sys.stderr)
         return 2
     with open(argv[0]) as fh:
         doc = json.load(fh)
-    if "traceEvents" in doc:
-        print(_report_chrome(doc, last))
-    elif "algorithms" in doc or "dynamic" in doc:
+    if "algorithms" in doc or "dynamic" in doc:
         print(_report_bench(doc, last))
     else:
-        print("unrecognized document (want a repro chrome-trace export "
-              "or a BENCH schema>=6 doc)", file=sys.stderr)
+        print("unrecognized document (want a BENCH schema>=6 doc)",
+              file=sys.stderr)
         return 2
     return 0
 

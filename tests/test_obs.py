@@ -1,4 +1,4 @@
-"""Telemetry substrate tests (DESIGN.md §16): rings, traces, spans, export.
+"""Telemetry substrate tests (DESIGN.md §16): rings, traces, spans, reports.
 
 Four layers, matching the module layout:
 
@@ -16,13 +16,21 @@ Four layers, matching the module layout:
   everything in one conflict-free step, a clique's final ``max_color``
   is its order, the serial-tail row drains its worklist with
   ``conflicts == 0``;
-* spans (compile-vs-execute jit attribution) and the Chrome-trace
-  export / text-report round-trip.
+* spans: ids and nesting (also on a second thread), the bounded ring,
+  ``last_requests``, the realtime clock, compile attribution from JAX's
+  monitoring events, the spans of one ``repro.color`` request, and the
+  text reports.
 """
 import json
+import threading
+import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+
+import repro
 
 from repro import api
 from repro.core import (
@@ -38,17 +46,15 @@ from repro.obs import (
     NF,
     HostRing,
     RunTrace,
-    chrome_trace,
     empty_trace,
-    export_chrome_trace,
-    jit_span,
+    last_requests,
     recorder,
     resolve_trace_cap,
     ring_rows,
     span,
 )
 from repro.obs import report as obs_report
-from repro.obs.spans import jit_key_seen
+from repro.obs import spans as obs_spans
 
 
 def _suite_graph():
@@ -259,6 +265,7 @@ def test_empty_graph_trace():
 
 
 def test_span_noop_without_recorder():
+    """A recorder sees only what closes inside it; the ring keeps all."""
     with span("never_kept"):
         pass
     with recorder() as rec:
@@ -266,33 +273,202 @@ def test_span_noop_without_recorder():
             pass
     assert [e.name for e in rec.events] == ["kept"]
     assert rec.events[0].meta == {"answer": 42}
+    assert [r.name for r, _ in last_requests(1, root="never_kept")] \
+        == ["never_kept"]
 
 
-def test_jit_span_compile_then_execute():
-    key = ("test_obs", "unique-key-A")
-    with recorder() as rec:
-        with jit_span("dispatch", key):
-            pass
-        with jit_span("dispatch", key):
-            pass
-    cats = [e.cat for e in rec.events]
-    assert cats == ["compile", "execute"]
-    agg = rec.by_name()["dispatch"]
-    assert agg["count"] == 2
-    assert agg["compile_seconds"] <= agg["seconds"]
+def test_span_ids_nest_across_threads():
+    seen = {}
+
+    def worker():
+        with span("t_root") as root:
+            with span("t_child") as child:
+                pass
+        seen.update(root=root, child=child)
+
+    with span("m_root") as m_root:
+        with span("m_child") as m_child:
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join()
+    assert m_root.parent_id == 0 and m_root.request_id == m_root.span_id
+    assert m_child.parent_id == m_root.span_id
+    assert m_child.request_id == m_root.span_id
+    # the worker thread starts with no open span: its spans form their own
+    # request, whatever the main thread has open
+    root, child = seen["root"], seen["child"]
+    assert root.parent_id == 0 and root.request_id == root.span_id
+    assert child.parent_id == root.span_id
+    assert child.request_id == root.span_id
+    ids = {m_root.span_id, m_child.span_id, root.span_id, child.span_id}
+    assert len(ids) == 4
 
 
-def test_jit_key_registry_advances_unrecorded():
-    """A dispatch nobody recorded still warms the key, so the first
-    *recorded* dispatch of a warm key is labeled execute, not compile."""
-    key = ("test_obs", "unique-key-B")
-    with jit_span("dispatch", key):          # no recorder active
+def test_spans_from_many_threads_keep_their_own_nesting():
+    """Threads open spans at once while another reads the ring: ids stay
+    unique and every child stays under its own thread's root."""
+    import sys
+
+    roots: dict = {}
+    kids: dict = {}
+    stop = threading.Event()
+
+    def worker(w):
+        for _ in range(100):
+            with span("stress_root", w=w) as root:
+                with span("stress_kid", w=w) as kid:
+                    pass
+            roots.setdefault(w, []).append(root)
+            kids.setdefault(w, []).append(kid)
+
+    def reader():
+        while not stop.is_set():
+            last_requests(5, root="stress_root")
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker, args=(w,))
+                   for w in range(8)]
+        r = threading.Thread(target=reader)
+        r.start()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        stop.set()
+        r.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not r.is_alive() and not any(t.is_alive() for t in threads)
+    ids = [e.span_id for w in roots for e in roots[w] + kids[w]]
+    assert len(ids) == len(set(ids)) == 8 * 100 * 2
+    for w in range(8):
+        for root, kid in zip(roots[w], kids[w]):
+            assert root.parent_id == 0 and root.meta["w"] == w
+            assert kid.parent_id == root.span_id == kid.request_id
+
+
+def test_span_ring_is_bounded_and_drops_oldest():
+    cap = obs_spans.RING_CAP
+    with span("ring_first"):
         pass
-    assert jit_key_seen(key) is True
-    with recorder() as rec:
-        with jit_span("dispatch", key):
+    for i in range(cap + 5):
+        with span("ring_fill", i=i):
             pass
-    assert rec.events[0].cat == "execute"
+    kept = list(obs_spans._RING)
+    assert len(kept) == cap
+    assert all(e.name == "ring_fill" for e in kept)
+    assert [e.meta["i"] for e in kept[:2]] == [5, 6]
+    assert kept[-1].meta["i"] == cap + 4
+    assert last_requests(1, root="ring_first") == []
+
+
+def test_last_requests_returns_the_last_roots_with_descendants():
+    made = []
+    for i in range(3):
+        with span("lr_root", i=i) as root:
+            with span("lr_mid"):
+                with span("lr_leaf", i=i):
+                    pass
+            made.append(root)
+        with span("lr_other"):
+            pass
+    out = last_requests(2, root="lr_root")
+    assert [r for r, _ in out] == made[1:]
+    for (root, desc), i in zip(out, (1, 2)):
+        assert [e.name for e in desc] == ["lr_leaf", "lr_mid"]
+        assert desc[0].meta == {"i": i}
+        assert all(e.request_id == root.span_id for e in desc)
+    assert len(last_requests(10, root="lr_root")) == 3
+    assert last_requests(0, root="lr_root") == []
+    assert last_requests(3, root="no_such_root") == []
+
+
+def test_span_stamps_are_on_the_realtime_clock():
+    before = time.time_ns()
+    with span("clock") as ev:
+        time.sleep(0.002)
+    after = time.time_ns()
+    assert before <= ev.start_ns < ev.end_ns <= after
+    assert ev.duration >= 0.002
+
+
+def _fresh_jitted():
+    def attributed(x):
+        return x * 3 + 2
+    return jax.jit(attributed)
+
+
+def test_compiles_are_counted_on_the_innermost_span():
+    x = jnp.ones(7, jnp.float32)
+    f = _fresh_jitted()
+    with span("outer") as outer:
+        with span("first") as first:
+            f(x).block_until_ready()
+        with span("second") as second:
+            f(x).block_until_ready()
+    assert first.meta.get("compiles", 0) >= 1
+    assert "compiles" not in second.meta
+    assert "compiles" not in outer.meta
+    with recorder() as rec:
+        with span("again"):
+            f(x).block_until_ready()
+    assert rec.by_name()["again"]["compiles"] == 0
+
+
+def test_cache_loads_are_not_counted_as_compiles(tmp_path):
+    """Under a warm persistent cache a new jit of the same program is a
+    cache load: counted as ``cache_loads``, not as a compile."""
+    from jax._src import compilation_cache
+
+    x = jnp.ones(11, jnp.float32)
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        compilation_cache.reset_cache()
+        with span("cold") as cold:
+            _fresh_jitted()(x).block_until_ready()
+        with span("warm") as warm:
+            _fresh_jitted()(x).block_until_ready()
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert cold.meta.get("compiles", 0) >= 1
+    assert cold.meta.get("cache_loads", 0) == 0
+    assert warm.meta.get("cache_loads", 0) >= 1
+    assert warm.meta.get("compiles", 0) == 0
+
+
+def test_color_request_spans():
+    """``repro.color`` opens a ``color`` root; the engine's super-steps and
+    serial tail nest under it with what the host knew of them."""
+    g = _suite_graph()
+    r = repro.color(g, "data_driven")
+    (root, desc), = last_requests(1)
+    assert root.name == "color" and root.parent_id == 0
+    assert root.meta["algorithm"] == "data_driven" and root.meta["n"] == g.n
+    assert all(e.request_id == root.span_id for e in desc)
+    names = [e.name for e in desc]
+    assert {"partition_plan", "csr_build", "superstep_loop"} <= set(names)
+    steps = [e for e in desc if e.name == "superstep"]
+    assert len(steps) == r.iterations - 2    # bootstrap and tail: no step
+    assert steps[0].meta["live"] == g.n and steps[0].meta["cells"] > 0
+    assert [e.meta["step"] for e in steps] == list(range(1, len(steps) + 1))
+    tail, = [e for e in desc if e.name == "serial_tail"]
+    assert tail.meta["vertices"] == g.n and tail.meta["stalled"]
+    assert 0 < tail.meta["live"] <= g.n
+    inner = sum(e.duration for e in desc if e.name in ("superstep",
+                                                       "serial_tail"))
+    assert inner <= root.duration
+    assert root.start_ns <= min(e.start_ns for e in desc)
+    assert root.end_ns >= max(e.end_ns for e in desc)
 
 
 def test_engine_spans_visible_to_outer_recorder():
@@ -326,33 +502,7 @@ def test_session_metrics_and_jit_cache_accounting():
     assert session.validate()
 
 
-# ------------------------------------------------------- export and report
-
-
-def test_chrome_export_roundtrip(tmp_path):
-    g = _suite_graph()
-    r = color_data_driven(g, mode="fused", trace=True)
-    path = tmp_path / "trace.json"
-    export_chrome_trace(str(path), {"fused/rmat-g": r})
-    doc = json.loads(path.read_text())
-    phases = {e["ph"] for e in doc["traceEvents"]}
-    assert phases <= {"M", "X", "C", "I"}
-    assert any(e["ph"] == "X" and e["name"] == "superstep_loop"
-               for e in doc["traceEvents"])
-    counters = [e for e in doc["traceEvents"] if e["ph"] == "C"]
-    assert len([e for e in counters if e["name"] == "worklist"]) \
-        == r.trace.steps.shape[0]
-    # otherData.repro reconstructs the full RunTrace
-    back = RunTrace.from_dict(doc["otherData"]["repro"]["fused/rmat-g"])
-    np.testing.assert_array_equal(back.steps, r.trace.steps)
-    # the text reporter accepts the exported file
-    assert obs_report.main([str(path)]) == 0
-
-
-def test_chrome_export_skips_untraced_runs():
-    g = _suite_graph()
-    doc = chrome_trace({"off": color_data_driven(g)})
-    assert doc["traceEvents"] == [] and doc["otherData"]["repro"] == {}
+# ------------------------------------------------------------------ report
 
 
 def test_report_formats():
@@ -362,7 +512,9 @@ def test_report_formats():
     assert "colors=" in line and "work=" in line
     table = obs_report.format_trace(r.trace, last=3)
     assert "live" in table and str(r.trace.iterations) in table
-    assert obs_report.format_spans(r.trace.spans).count("\n") >= 1
+    spans_table = obs_report.format_spans(r.trace.spans)
+    assert spans_table.count("\n") >= 1
+    assert spans_table.splitlines()[0].split()[-2:] == ["compiles", "loads"]
     block = obs_report.format_metrics({"a": 1, "bb": 2.5}, "t:")
     assert block.splitlines()[0] == "t:" and " a " in block
 
