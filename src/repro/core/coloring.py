@@ -493,22 +493,261 @@ def provider_tail(provider, colors_ext, wl, *, kind="bitset"):
     return serial_tail_step(provider.row1, colors_ext, wl, kind)
 
 
-def _dispatch_tail(provider, colors_ext, wl, *, kind, use_kernel, width):
-    """Route the serial tail: on-device CSR kernel vs the fori_loop driver.
+# --------------------------------------------------------------------------
+# the round tail: serial_tail_step's coloring, one dependency round at a time
+# --------------------------------------------------------------------------
+# Sequential greedy in a fixed order gives a vertex the smallest color no
+# neighbor holds at its turn: settled neighbors (outside the tail) and the
+# tail neighbors earlier in the order (its predecessors); later ones are
+# still cleared.  So a vertex can take its color as soon as every
+# predecessor has one, and every tail vertex whose predecessors are all
+# colored can take it in the same round: such vertices are never adjacent
+# (of two adjacent tail vertices one precedes the other).  The state array
+# holds -1 for an uncolored tail vertex, so one gather of a round's tile
+# gives both the colors FirstFit reads (-1 reads as 0) and the successors
+# whose predecessor counters the round decrements.  The number of rounds is
+# the longest predecessor chain in the order.
+
+ROUND_CAP_FLOOR = 256  # fewest lanes a round dispatches per class (jit keys)
+TAIL_MIN_WIDTH = 16    # narrowest tail class (jit keys)
+PREFIX_BLOCK = 1024    # lanes per block of the blocked prefix count
+
+
+def _tail_tile(provider, ids, width):
+    """``provider.rows(ids, width).T``: the round tail's lane-major tile.
+
+    ``DeviceCSR`` gathers it lane-major (``gather_rows_t``): the tail's
+    classes are narrow (rmat-g's largest holds 872,309 vertices of degree
+    at most 16), and a ``(w, 16)`` tile pads every row to the TPU's 128
+    lanes.
+    """
+    if isinstance(provider, DeviceCSR):
+        return provider.gather_rows_t(
+            ids, provider.max_width if width is None else width)
+    return provider.rows(ids, width).T
+
+
+def _prefix_sum(flags):
+    """Running sum of ``flags`` (bool or int32) along the last axis, in blocks.
+
+    Same numbers as ``jnp.cumsum``; the v5e compiler takes about 26 s over
+    one 2^20-long cumsum and under 2 s over 1,024-wide blocks.
+    """
+    n = flags.shape[-1]
+    pad = -n % PREFIX_BLOCK
+    f = jnp.pad(flags.astype(jnp.int32), [(0, 0)] * (flags.ndim - 1)
+                + [(0, pad)])
+    f = f.reshape(*flags.shape[:-1], -1, PREFIX_BLOCK)
+    within = jnp.cumsum(f, axis=-1, dtype=jnp.int32)
+    block = within[..., -1]
+    before = jnp.cumsum(block, axis=-1, dtype=jnp.int32) - block
+    out = within + before[..., None]
+    return out.reshape(*flags.shape[:-1], -1)[..., :n]
+
+
+def _csr_lanes_below(provider, key):
+    """For each vertex ``v < n``, the lanes of its ``DeviceCSR`` row whose
+    neighbor ``u`` has ``key[u] < key[v]``: the lanes ``gather_rows``
+    returns, counted along C itself, so no lane is padding.
+
+    Each entry's row key comes from a running sum of key differences put
+    at the row starts (rows that share a start, the empty ones, cancel),
+    so ``|key|`` must stay below 2^30.
+    """
+    n = provider.n
+    starts = provider.row_starts[:n]
+    diff = key[:n] - jnp.concatenate([jnp.zeros(1, key.dtype), key[:n - 1]])
+    # row starts are sorted: saying so takes the v5e compiler from ~12 s to
+    # under 1 s over rmat-g's 2^20 starts into 10.5M entries
+    marks = jnp.zeros(provider.col_padded.shape, key.dtype).at[starts].add(
+        diff, indices_are_sorted=True)
+    row_key = _prefix_sum(marks)
+    below = take_tile(key, provider.col_padded) < row_key
+    seen = jnp.concatenate([jnp.zeros(1, jnp.int32), _prefix_sum(below)])
+    return (jnp.take(seen, starts + provider.deg_ext[:n], mode="fill",
+                     fill_value=0) - seen[starts])
+
+
+@partial(jax.jit, static_argnames=("widths", "by_entries"))
+def provider_tail_setup(provider, colors_ext, tail_wl, members, *, widths,
+                        by_entries=False):
+    """State, predecessor counters and class of every tail vertex.
+
+    ``tail_wl`` is the tail in ``order_tail`` order (sentinel padding last);
+    ``members[k]`` holds the tail vertices of class ``k`` (sentinel-padded),
+    whose rows ``provider.rows(ids, widths[k])`` gathers.  Ranks are
+    positions in ``tail_wl``; settled vertices and the sentinel rank past
+    every tail vertex, so they are never counted as predecessors.
+    ``by_entries`` counts along a ``DeviceCSR``'s C once instead of over
+    the classes' padded tiles: the same lanes, the same counts.
+    """
+    n = colors_ext.shape[0] - 1
+    last = tail_wl.shape[0]
+    rank = jnp.full((n + 1,), last, jnp.int32).at[tail_wl].set(
+        jnp.arange(last, dtype=jnp.int32)).at[n].set(last)
+    state = colors_ext.at[tail_wl].set(-1).at[n].set(0)
+    cls = jnp.full((n + 1,), len(members), jnp.int32)
+    for k, ids in enumerate(members):
+        cls = cls.at[ids].set(k)
+    cls = cls.at[n].set(len(members))
+    if by_entries:
+        cnt = jnp.concatenate([_csr_lanes_below(provider, rank),
+                               jnp.zeros(1, jnp.int32)])
+        return state, jnp.where(rank < last, cnt, 0), cls
+    cnt = jnp.zeros((n + 1,), jnp.int32)
+    for ids, width in zip(members, widths):
+        pred = take_tile(rank, _tail_tile(provider, ids, width)) < rank[ids]
+        cnt = cnt.at[ids].set(jnp.sum(pred, axis=0, dtype=jnp.int32))
+    return state, cnt, cls
+
+
+@partial(jax.jit, static_argnames=("classes",))
+def provider_tail_frontier(state, cnt, cls, *, classes):
+    """Per-class running counts of the uncolored tail vertices with no
+    uncolored predecessor: row ``k`` of the ``(classes, n)`` counts numbers
+    class ``k``'s frontier; its last column is the class count."""
+    n = state.shape[0] - 1
+    ready = (state[:n] == -1) & (cnt[:n] == 0)
+    member = cls[None, :n] == jnp.arange(classes, dtype=jnp.int32)[:, None]
+    csum = _prefix_sum(ready[None, :] & member)
+    return csum, csum[:, -1]
+
+
+def _nth_true(csum, cap):
+    """Where the running count ``csum`` first reaches 1, 2, ..., ``cap``:
+    the positions of the first ``cap`` true flags, ``n`` past the last.
+
+    ``jnp.searchsorted`` halves all n positions, one gather of ``cap``
+    lanes per halving.  Here one compare against the last count of each
+    of at most 1,024 blocks finds the block, and the search halves only
+    its positions: 11 gathers, not 21, at rmat-g's n = 2^20.
+    """
+    n = csum.shape[0]
+    block = max(PREFIX_BLOCK, next_pow2(-(-n // PREFIX_BLOCK)))
+    j = jnp.arange(1, cap + 1, dtype=csum.dtype)
+    ends = csum[jnp.minimum(jnp.arange(block - 1, n + block - 1, block),
+                            n - 1)]
+    lo = jnp.sum(ends[None, :] < j[:, None], axis=1, dtype=jnp.int32)
+    lo = jnp.minimum(lo * block, n)
+    hi = jnp.minimum(lo + block, n)
+
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = (lo + hi) // 2
+        right = (lo < hi) & (jnp.take(csum, mid, mode="fill",
+                                      fill_value=cap + 1) < j)
+        return (jnp.where(right, mid + 1, lo),
+                jnp.where((lo < hi) & ~right, mid, hi))
+
+    lo, _ = lax.fori_loop(0, block.bit_length(), halve, (lo, hi))
+    return lo
+
+
+@partial(jax.jit, static_argnames=("cap", "width", "kind"))
+def provider_tail_round(provider, state, cnt, csum, k, *, cap, width, kind):
+    """Color class ``k``'s frontier (at most ``cap`` vertices) by FirstFit
+    and take them off their successors' predecessor counters."""
+    n = state.shape[0] - 1
+    ids = _nth_true(csum[k], cap)
+    rows = _tail_tile(provider, ids, width)
+    nc = take_tile(state, rows)
+    valid = ids < n
+    c = jnp.where(valid, FF_FUNCS[kind](jnp.maximum(nc, 0).T), 0)
+    state = state.at[ids].set(c.astype(state.dtype))
+    # an uncolored tail lane of a frontier vertex is one of its successors
+    succ = (nc < 0) & valid
+    cnt = cnt.at[jnp.where(succ, rows, n + 1)].add(-1, mode="drop")
+    return state, cnt
+
+
+def _tail_classes(provider, tail_ids):
+    """(class id arrays, widths) that ``round_tail`` splits the tail into.
+
+    A ``DeviceCSR`` tail splits by row length rounded up to a power of two
+    (at least ``TAIL_MIN_WIDTH``, at most the provider's width), so a
+    tile's lanes are at most twice its entries; the engine's degree
+    classes are wider (rmat-g's reach from degree 33 to 376).  Other
+    providers gather their full width whatever is asked: one class.
+    """
+    if not isinstance(provider, DeviceCSR):
+        return [tail_ids], [None]
+    deg = np.asarray(provider.deg_ext)[tail_ids]
+    widths = np.minimum(
+        1 << np.ceil(np.log2(np.maximum(deg, TAIL_MIN_WIDTH))).astype(int),
+        provider.max_width)
+    ws = sorted(set(widths.tolist()), reverse=True)
+    return [tail_ids[widths == w] for w in ws], ws
+
+
+def round_tail(provider, colors_ext, tail_wl, tail_ids, kind="bitset"):
+    """The serial tail's coloring of ``tail_wl``, by dependency rounds.
+
+    Bit-identical to ``serial_tail_step(provider.row1, colors_ext,
+    tail_wl, kind)`` for a provider whose rows are symmetric with
+    multiplicity (``v`` lies in ``u``'s row as often as ``u`` in ``v``'s):
+    every FirstFit ``kind`` returns the smallest free color, and a
+    frontier vertex reads exactly the colors the sequential loop would
+    show it (see the comment above ``ROUND_CAP_FLOOR``).  ``tail_ids``
+    holds the real entries of ``tail_wl`` on the host; each round
+    dispatches every class's frontier (``_tail_classes``) at a power-of-two
+    lane count of at least ``ROUND_CAP_FLOOR``.  One count readback per
+    round, as a super-step.  Returns ``(colors_ext, rounds)``.
+    """
+    n = colors_ext.shape[0] - 1
+    members, widths = _tail_classes(provider, np.asarray(tail_ids, np.int32))
+    padded = []
+    for m in members:
+        buf = np.full(max(ROUND_CAP_FLOOR, next_pow2(m.size)), n, np.int32)
+        buf[:m.size] = m
+        padded.append(jnp.asarray(buf))
+    widths = tuple(widths)
+    # count along C where the classes' tiles would gather more lanes
+    by_entries = isinstance(provider, DeviceCSR) and sum(
+        int(p.shape[0]) * w for p, w in zip(padded, widths)
+    ) > provider.col_padded.shape[0]
+    state, cnt, cls = provider_tail_setup(provider, colors_ext, tail_wl,
+                                          tuple(padded), widths=widths,
+                                          by_entries=by_entries)
+    todo = sum(int(m.size) for m in members)
+    rounds = 0
+    while todo:
+        csum, counts = provider_tail_frontier(state, cnt, cls,
+                                              classes=len(members))
+        counts = np.asarray(counts).tolist()
+        if not any(counts):
+            raise ValueError(
+                f"serial tail: {todo} vertices wait on predecessors that "
+                "never take a color; the provider's rows are not symmetric")
+        for k, c in enumerate(counts):
+            if c:
+                state, cnt = provider_tail_round(
+                    provider, state, cnt, csum, k,
+                    cap=max(ROUND_CAP_FLOOR, next_pow2(c)), width=widths[k],
+                    kind=kind)
+        todo -= sum(counts)
+        rounds += 1
+    return state, rounds
+
+
+def _dispatch_tail(provider, colors_ext, wl, tail_ids, *, kind, use_kernel,
+                   width):
+    """Run the serial tail over ``wl`` (``order_tail`` order; ``tail_ids``
+    its real entries on the host): ``(colors_ext, rounds)``.
 
     ``use_kernel="csr"`` with a ``DeviceCSR`` provider runs the §18 grid=1
-    sequential kernel (one dispatch, live aliased color state); every other
-    configuration keeps the ``serial_tail_step`` fori_loop.  Both compute
-    the same sequential greedy — every FirstFit ``kind`` returns the
-    smallest free color, so the kernel is kind-agnostic and bit-identical.
+    sequential kernel (one dispatch, live aliased color state, one step
+    per vertex); every other configuration runs ``round_tail``.  Both give
+    ``serial_tail_step``'s sequential greedy bit for bit: every FirstFit
+    ``kind`` returns the smallest free color, and a round colors exactly
+    the vertices whose predecessors in the order are all colored.
     """
     if use_kernel == "csr" and isinstance(provider, DeviceCSR):
         from repro.kernels.superstep.csr_kernel import serial_tail_csr_tpu
 
         return serial_tail_csr_tpu(
             provider.row_starts, provider.col_padded, provider.deg_ext,
-            colors_ext, wl, width)
-    return provider_tail(provider, colors_ext, wl, kind=kind)
+            colors_ext, wl, width), len(tail_ids)
+    return round_tail(provider, colors_ext, wl, tail_ids, kind)
 
 
 def _tiled_superstep(provider, deg_ext, colors_ext, wls, *, widths, heuristic,
@@ -579,8 +818,11 @@ def run_ragged_engine(
     ``padded_work`` charges ``lanes × acc_widths[k]`` gather cells.  When the
     total live count drops to ``tail_threshold`` — or the worklist *stalls*
     (a post-bootstrap step retires under 1/STALL_DEN of it, the signature of
-    a cascading grid/circuit graph) — the remaining entries are handed to ONE
-    ``serial_tail_step`` over the provider's full-width rows.  ``mode`` picks
+    a cascading grid/circuit graph) — the remaining entries are handed to the
+    serial tail: ``serial_tail_step``'s largest-degree-first sequential
+    greedy, computed by ``round_tail`` one dependency round at a time (a
+    vertex takes its color once every earlier neighbor in the order has
+    one, so the colors are the sequential loop's bit for bit).  ``mode`` picks
     the host-loop (``workefficient``) or single-device-program (``fused``)
     realization of the *same* schedule — colors are bit-identical.
 
@@ -703,19 +945,19 @@ def run_ragged_engine(
         if stalled and stall_serializes_all:
             # speculation failed to make progress — discard it and run one
             # clean largest-degree-first sequential greedy over the graph
-            tail_np = np.arange(n, dtype=np.int32)
+            tail_ids = tail_np = np.arange(n, dtype=np.int32)
         else:
-            live = np.concatenate(
+            tail_ids = np.concatenate(
                 [np.asarray(wls[k][:counts[k]]) for k in range(K) if counts[k]]
             )
             tail_np = np.full(min(next_pow2(total), n), n, np.int32)
-            tail_np[:total] = live
+            tail_np[:total] = tail_ids
         with span("serial_tail", live=total, stalled=stalled,
-                  vertices=int(tail_np.shape[0])):
+                  vertices=int(tail_np.shape[0])) as sp:
             tail_wl = order_tail(jnp.asarray(tail_np), deg_ext)
-            colors_ext = _dispatch_tail(provider, colors_ext, tail_wl,
-                                        kind=kind, use_kernel=use_kernel,
-                                        width=tail_width)
+            colors_ext, sp.meta["rounds"] = _dispatch_tail(
+                provider, colors_ext, tail_wl, tail_ids, kind=kind,
+                use_kernel=use_kernel, width=tail_width)
             # the readback below waits for this buffer anyway: the span
             # ends when the device is done with the tail
             colors_ext.block_until_ready()
@@ -848,13 +1090,16 @@ def _run_ragged_fused(
         with span("serial_tail", live=total, stalled=stalled) as sp:
             if stalled and stall_serializes_all:
                 tail_wl = order_tail(jnp.arange(n, dtype=jnp.int32), deg_ext)
+                tail_ids = np.arange(n, dtype=np.int32)
             else:
                 combined = jnp.concatenate(list(wls)) if K > 1 else wls[0]
                 tail_wl = order_tail(combined, deg_ext)
+                tail_ids = np.concatenate(
+                    [np.asarray(w[:int(c)]) for w, c in zip(wls, counts)])
             sp.meta["vertices"] = int(tail_wl.shape[0])
-            colors_ext = _dispatch_tail(provider, colors_ext, tail_wl,
-                                        kind=kind, use_kernel=use_kernel,
-                                        width=tail_width)
+            colors_ext, sp.meta["rounds"] = _dispatch_tail(
+                provider, colors_ext, tail_wl, tail_ids, kind=kind,
+                use_kernel=use_kernel, width=tail_width)
             colors_ext.block_until_ready()  # the readback waits for it anyway
         work_items += n if stalled and stall_serializes_all else total
         tail_cells = int(tail_wl.shape[0]) * tail_width

@@ -217,6 +217,38 @@ def test_recolor_after_churn_is_valid(mode):
             s.graph, oracle.colors) == True  # noqa: E712
 
 
+@pytest.mark.parametrize("mode", ["fused", "workefficient"])
+def test_stalled_warm_start_tail_matches_the_sequential_tail(monkeypatch,
+                                                             mode):
+    """A churn delta whose frontier stalls hands the live worklist, among
+    settled colors, to the round tail: each tail's colors equal the
+    one-vertex-at-a-time ``provider_tail`` over the same inputs."""
+    import repro.core.coloring as C
+    from repro.graphs import rmat
+    from repro.obs.spans import SpanRecorder
+
+    round_tail, checked = C.round_tail, []
+
+    def checking(provider, colors_ext, wl, tail_ids, kind="bitset"):
+        out, rounds = round_tail(provider, colors_ext, wl, tail_ids, kind)
+        want = C.provider_tail(provider, colors_ext, wl, kind=kind)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+        checked.append(rounds)
+        return out, rounds
+
+    monkeypatch.setattr(C, "round_tail", checking)
+    s = open_session(rmat(4000, 9.0, seed=12), mode=mode)
+    checked.clear()  # the cold start's tail, if any, is not the point
+    rem, add = _churn(s.graph, 0.3, np.random.default_rng(3))
+    s.apply_delta(remove_edges=rem, add_edges=add)
+    with SpanRecorder() as rec:
+        r = s.recolor()
+    tails = [e.meta for e in rec.events if e.name == "serial_tail"]
+    assert [t["stalled"] for t in tails] == [True]
+    assert checked == [tails[0]["rounds"]] and checked[0] > 1
+    assert r.converged and s.validate()
+
+
 def test_insertion_conflict_is_repaired():
     # two same-colored vertices forced adjacent must split colors
     g = grid2d(10, 10)  # bipartite: 2 colors, lots of same-color pairs

@@ -15,6 +15,7 @@ import jax
 
 import repro.core.coloring as C
 from repro.core import (
+    CSRGraph,
     DeviceCSR,
     auto_tile_thresholds,
     color_data_driven,
@@ -452,6 +453,196 @@ def test_csr_tail_matches_serial_tail_oracle(gname, kind):
     got = serial_tail_csr_tpu(dev.row_starts, dev.col_padded, dev.deg_ext,
                               colors_ext, wl, W)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+# --------------------------------------------------------------------------
+# the round tail: serial_tail_step's coloring by dependency rounds
+# --------------------------------------------------------------------------
+
+TAIL_SHAPES = ["stalled_all", "live_subset", "padded_subset"]
+
+
+def _round_tail_inputs(g, shape, dense=False):
+    """(graph, provider, colors_ext, ordered tail, tail ids) for one
+    of the three tail shapes the engine hands over: every vertex from a
+    cleared state (the cold stall), a live third of the vertices among
+    settled colors (a warm start), and that subset plus degree-0 vertices
+    and trailing sentinels (a pow2-padded buffer)."""
+    n0 = g.n
+    if shape == "padded_subset":  # five isolated vertices at the end
+        g = CSRGraph(np.concatenate([g.row_offsets,
+                                     np.full(5, g.row_offsets[-1])]),
+                     g.col_indices)
+    n = g.n
+    dev = DeviceCSR.from_csr(g)
+    provider = C.DenseRows(jnp.asarray(g.padded_adjacency())) if dense \
+        else dev
+    rng = np.random.default_rng(41)
+    if shape == "stalled_all":
+        colors = np.zeros(n, np.int32)
+        tail = np.arange(n, dtype=np.int32)
+    else:
+        colors = rng.integers(1, dev.max_width + 2, n).astype(np.int32)
+        tail = rng.choice(n0, n0 // 3, replace=False).astype(np.int32)
+        if shape == "padded_subset":
+            tail = np.concatenate([tail, np.arange(n0, n, dtype=np.int32)])
+    colors_ext = jnp.asarray(np.concatenate([colors, [0]]).astype(np.int32))
+    wl = tail
+    if shape == "padded_subset":
+        wl = np.concatenate([wl, np.full(7, n, np.int32)])
+    wl = C.order_tail(jnp.asarray(wl), dev.deg_ext)
+    return g, provider, colors_ext, wl, tail
+
+
+def _longest_predecessor_chain(g, order):
+    """Plain reference: the longest path of tail vertices, each adjacent to
+    and earlier in ``order`` than the next."""
+    rank = {int(v): i for i, v in enumerate(order)}
+    level = {}
+    for v in order:
+        v = int(v)
+        level[v] = 1 + max((level[int(u)] for u in g.neighbors(v)
+                            if rank.get(int(u), len(rank)) < rank[v]),
+                           default=0)
+    return max(level.values(), default=0)
+
+
+@pytest.mark.parametrize("shape", TAIL_SHAPES)
+@pytest.mark.parametrize("gname", ["er", "grid", "powerlaw"])
+@pytest.mark.parametrize("kind", ["bitset", "scan"])
+def test_round_tail_matches_serial_tail_step(gname, kind, shape):
+    """Bit for bit the fori_loop's sequential greedy: a frontier vertex
+    reads its predecessors' colors and the settled ones, and its later
+    neighbors still cleared, as the loop shows it at its turn."""
+    g, dev, colors_ext, wl, tail = _round_tail_inputs(GRAPHS[gname](), shape)
+    want = C.serial_tail_step(dev.row1, colors_ext, wl, kind)
+    got, rounds = C.round_tail(dev, colors_ext, wl, tail, kind)
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+    assert 0 < rounds <= tail.size
+
+
+@pytest.mark.parametrize("shape", TAIL_SHAPES)
+def test_round_tail_matches_serial_tail_step_on_dense_rows(shape):
+    g, dense, colors_ext, wl, tail = _round_tail_inputs(
+        GRAPHS["powerlaw"](), shape, dense=True)
+    want = C.serial_tail_step(dense.row1, colors_ext, wl, "bitset")
+    got, _ = C.round_tail(dense, colors_ext, wl, tail)
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+@pytest.mark.parametrize("gname", sorted(GRAPHS))
+def test_round_tail_stalled_all_is_largest_degree_first_greedy(gname):
+    g, dev, colors_ext, wl, tail = _round_tail_inputs(GRAPHS[gname](),
+                                                      "stalled_all")
+    got, _ = C.round_tail(dev, colors_ext, wl, tail)
+    np.testing.assert_array_equal(
+        np.asarray(got)[:g.n], greedy_serial(g, "largest_degree_first"))
+
+
+@pytest.mark.parametrize("shape", TAIL_SHAPES)
+@pytest.mark.parametrize("gname", sorted(GRAPHS))
+def test_round_tail_rounds_are_the_longest_predecessor_chain(gname, shape):
+    g, dev, colors_ext, wl, tail = _round_tail_inputs(GRAPHS[gname](), shape)
+    _, rounds = C.round_tail(dev, colors_ext, wl, tail)
+    order = np.asarray(wl)
+    assert rounds == _longest_predecessor_chain(g, order[order < g.n])
+
+
+@pytest.mark.parametrize("gname", sorted(GRAPHS))
+def test_tail_classes_partition_the_tail_at_covering_widths(gname):
+    g = GRAPHS[gname]()
+    dev = DeviceCSR.from_csr(g)
+    tail = np.random.default_rng(5).permutation(g.n)[:g.n // 2].astype(
+        np.int32)
+    members, widths = C._tail_classes(dev, tail)
+    assert sorted(np.concatenate(members).tolist()) == sorted(tail.tolist())
+    for m, w in zip(members, widths):
+        assert w == dev.max_width or (w >= C.TAIL_MIN_WIDTH and w & (w - 1) == 0)
+        assert g.degrees[m].max() <= w
+        assert w == C.TAIL_MIN_WIDTH or g.degrees[m].max() > w // 2
+    dense = C.DenseRows(jnp.asarray(g.padded_adjacency()))
+    assert C._tail_classes(dense, tail)[1] == [None]
+
+
+@pytest.mark.parametrize("shape", TAIL_SHAPES)
+@pytest.mark.parametrize("gname", sorted(GRAPHS))
+def test_tail_counters_along_c_match_the_tiles(gname, shape):
+    """The predecessor counters counted once along C (a large tail) equal
+    those counted over the classes' padded tiles (a small one)."""
+    g, dev, colors_ext, wl, tail = _round_tail_inputs(GRAPHS[gname](), shape)
+    members, widths = C._tail_classes(dev, tail)
+    padded = tuple(jnp.asarray(np.concatenate(
+        [m, np.full(C.ROUND_CAP_FLOOR, g.n, np.int32)])) for m in members)
+    tiles, along_c = (C.provider_tail_setup(
+        dev, colors_ext, wl, padded, widths=tuple(widths), by_entries=b)
+        for b in (False, True))
+    for a, b in zip(tiles, along_c):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.asarray(tiles[1]).max() > 0
+
+
+@pytest.mark.parametrize("case", ["distance2", "bipartite"])
+def test_round_tail_matches_serial_tail_on_two_hop_rows(case):
+    """Composed two-hop rows carry duplicate and self lanes; their
+    multiplicities are symmetric (paths of length one and two, shared
+    rows), so every lane counted against a vertex is taken off again."""
+    from repro.d2.bipartite import BipartiteGraph
+    from repro.d2.coloring import TwoHopRows
+    from repro.graphs import jacobian_tall_skinny
+
+    if case == "distance2":
+        g = erdos_renyi(300, 4.0, seed=5)
+        adj = jnp.asarray(g.padded_adjacency())
+        prov, deg = TwoHopRows(adj, adj, include_first_hop=True), g.degrees
+    else:
+        bg = jacobian_tall_skinny(200, 24, nnz_per_row=3, seed=1)
+        assert isinstance(bg, BipartiteGraph)
+        c2r, r2c = bg.padded_halves()
+        prov = TwoHopRows(jnp.asarray(c2r), jnp.asarray(r2c),
+                          include_first_hop=False)
+        deg = bg.col_degrees
+    n = int(deg.size)
+    deg_ext = jnp.asarray(np.concatenate([deg, [0]]).astype(np.int32))
+    rng = np.random.default_rng(43)
+    colors = rng.integers(1, 6, n).astype(np.int32)
+    for tail in (np.arange(n, dtype=np.int32),
+                 rng.choice(n, n // 2, replace=False).astype(np.int32)):
+        colors_ext = jnp.asarray(np.concatenate([colors, [0]]))
+        wl = C.order_tail(jnp.asarray(tail), deg_ext)
+        want = C.serial_tail_step(prov.row1, colors_ext, wl, "bitset")
+        got, _ = C.round_tail(prov, colors_ext, wl, tail)
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+@pytest.mark.parametrize("n", [1, 1000, 1024, 3000])
+@pytest.mark.parametrize("share", [0.0, 0.02, 1.0])
+def test_nth_true_matches_searchsorted(n, share):
+    """The frontier pick: the block-then-halve search finds the positions
+    ``jnp.searchsorted`` finds, the sentinel n past the last true flag."""
+    flags = np.random.default_rng(n).random(n) < share
+    csum = jnp.cumsum(jnp.asarray(flags), dtype=jnp.int32)
+    for cap in (1, 256, 4096):
+        want = jnp.searchsorted(csum, jnp.arange(1, cap + 1, dtype=jnp.int32),
+                                side="left")
+        np.testing.assert_array_equal(np.asarray(C._nth_true(csum, cap)),
+                                      np.asarray(want))
+
+
+def test_prefix_sum_matches_cumsum():
+    x = np.random.default_rng(3).integers(-5, 6, (3, 5000)).astype(np.int32)
+    np.testing.assert_array_equal(np.asarray(C._prefix_sum(jnp.asarray(x))),
+                                  np.cumsum(x, axis=1))
+
+
+def test_round_tail_refuses_rows_that_are_not_symmetric():
+    # vertex 1 lists 0, which precedes it; 0 does not list 1, so 1 waits
+    # for a decrement that never comes
+    adj = jnp.asarray(np.array([[3], [0], [3]], np.int32))
+    colors_ext = jnp.zeros(4, jnp.int32)
+    wl = jnp.arange(3, dtype=jnp.int32)
+    with pytest.raises(ValueError, match="not symmetric"):
+        C.round_tail(C.DenseRows(adj), colors_ext, wl,
+                     np.arange(3, dtype=np.int32))
 
 
 def _eqn_shapes(jaxpr, out):

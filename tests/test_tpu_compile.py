@@ -23,6 +23,10 @@ WIDTHS = [16, 64, 376]          # narrow class, mid class, rmat-g's max degree
 RMAT_G_N = 1_048_576
 RMAT_G_ENTRIES = 10_473_742
 RMAT_G_CLASSES = ((45241, 376), (131026, 32), (242471, 16), (629838, 8))
+# the round tail's classes of rmat-g's stalled tail: row lengths rounded up
+# to powers of two, at least 16
+RMAT_G_TAIL_CLASSES = ((10, 376), (291, 256), (5801, 128), (39139, 64),
+                       (131026, 32), (872309, 16))
 
 
 @pytest.fixture(scope="module")
@@ -115,6 +119,38 @@ def test_ragged_superstep_compiles_for_v5e_at_rmat_g_size(one_chip):
                heuristic="degree", kind="bitset", use_kernel=False,
                chunks=(1,) * K, pack_degrees=True)
     assert "tpu_custom_call" not in hlo   # pure JAX: no kernel expected
+
+
+@pytest.mark.parametrize("program", ["setup", "frontier", "round_wide",
+                                     "round_narrow"])
+def test_round_tail_compiles_for_v5e_at_rmat_g_size(one_chip, program):
+    """The round tail's programs as rmat-g's stalled tail runs them: the
+    counters along C, the per-class frontier counts, and the rounds of
+    its widest (376 x 256) and largest (16 x 65,536) tiles."""
+    from repro.core import coloring as C
+    from repro.core.csr import DeviceCSR, next_pow2
+
+    n, dmax = RMAT_G_N, RMAT_G_CLASSES[0][1]
+    provider = DeviceCSR(_spec(one_chip, (n + 1,)),
+                         _spec(one_chip, (RMAT_G_ENTRIES + dmax,)),
+                         _spec(one_chip, (n + 1,)), n, dmax)
+    vec = _spec(one_chip, (n + 1,))
+    K = len(RMAT_G_TAIL_CLASSES)
+    if program == "setup":
+        members = tuple(_spec(one_chip, (max(256, next_pow2(c)),))
+                        for c, _ in RMAT_G_TAIL_CLASSES)
+        hlo = _hlo(C.provider_tail_setup, provider, vec,
+                   _spec(one_chip, (n,)), members,
+                   widths=tuple(W for _, W in RMAT_G_TAIL_CLASSES),
+                   by_entries=True)
+    elif program == "frontier":
+        hlo = _hlo(C.provider_tail_frontier, vec, vec, vec, classes=K)
+    else:
+        cap, width = (256, 376) if program == "round_wide" else (65536, 16)
+        hlo = _hlo(C.provider_tail_round, provider, vec, vec,
+                   _spec(one_chip, (K, n)), _spec(one_chip, ()), cap=cap,
+                   width=width, kind="bitset")
+    assert "tpu_custom_call" not in hlo
 
 
 def test_auto_backend_is_jax_on_tpu_and_csr_kernel_refused(monkeypatch):
