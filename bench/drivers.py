@@ -31,14 +31,16 @@ def _compared(improper: int) -> dict:
 
 
 def oneshot(run) -> dict:
-    """One caller colors the seed's graph, closed loop.  Every request hands
-    ``repro.color`` a fresh ``CSRGraph`` over the generated arrays, so it
-    pays for the CSR build and upload as a user coloring a graph once does,
-    and ends with the colors on the host."""
-    g, traffic = run.graph, run.traffic
+    """One caller colors the run's graphs, closed loop, in whole cycles over
+    ``run.graphs``.  Every request hands ``repro.color`` a fresh
+    ``CSRGraph`` over the generated arrays, so it pays for the CSR build and
+    upload as a user coloring a graph once does, and ends with the colors on
+    the host.  Set-up colors each graph once: orders can reach different
+    program shapes, and none may compile inside the window."""
+    graphs, traffic = run.graphs, run.traffic
     options = dict(traffic.get("options", {}))
 
-    def request():
+    def request(g):
         with run.annotate("color"):
             r = run.repro.color(run.CSRGraph(g.row_offsets, g.col_indices),
                                 traffic["algorithm"], **options)
@@ -47,12 +49,14 @@ def oneshot(run) -> dict:
         return r, colors
 
     with run.annotate("warmup"):
-        request()
+        for g in graphs:
+            request(g)
     answers, iterations, num_colors, failed = [], [], [], 0
     with run.window():
         while True:
+            g = graphs[len(answers) % len(graphs)]
             try:
-                r, colors = request()
+                r, colors = request(g)
             except Exception as e:  # a failed request counts, the window goes on
                 run.note_failure(e)
                 failed += 1
@@ -65,10 +69,14 @@ def oneshot(run) -> dict:
                 break
 
     def check():
-        src, dst = g.undirected_edges()
-        return _compared(sum(
-            g.n if c is None else reference.improper_vertices(g.n, src, dst, c)
-            for c in answers))
+        improper = 0
+        for i, g in enumerate(graphs):
+            src, dst = g.undirected_edges()
+            improper += sum(
+                g.n if c is None
+                else reference.improper_vertices(g.n, src, dst, c)
+                for c in answers[i::len(graphs)])
+        return _compared(improper)
 
     return {
         "attempted": len(answers),

@@ -84,15 +84,19 @@ def cell_spec(workload: str) -> dict:
 
 
 def seed_rng(seed: int, stream: int) -> np.random.Generator:
-    """An independent stream per use, all drawn from the run's seed."""
+    """An independent stream per use, all drawn from one seed."""
     return np.random.default_rng(np.random.SeedSequence([stream, seed % 2**64]))
 
 
-def generate_graph(config: dict, seed: int):
-    """The configuration's graph.  A ``graph_seed`` fixes the generator's
-    draw; ``relabel`` then renames the vertices by a permutation drawn from
-    the run's seed, so every run colors one graph, with the same sizes and
-    compiled shapes, in another vertex order."""
+def generate_graphs(config: dict, seed: int) -> list:
+    """The configuration's graphs, in the sequence a run's cycle visits them.
+
+    A ``graph_seed`` fixes the generator's draw; without one the run's seed
+    draws it.  ``orders`` (K) then renames the vertices of that one draw by
+    K permutations from fixed streams of ``graph_seed``: every run colors
+    the same K graphs, with the same sizes and compiled shapes, in K vertex
+    orders.  The run's seed only rotates the sequence: it changes which
+    order comes first, not the set."""
     from bench.graphs.csr import relabel
 
     gen = load_module(BENCH / "graphs" / f"{config['generator']}.py",
@@ -100,9 +104,16 @@ def generate_graph(config: dict, seed: int):
     fixed = config.get("graph_seed")
     g = gen.generate(config["graph"], seed_rng(seed, 0) if fixed is None
                      else np.random.default_rng(fixed))
-    if config.get("relabel"):
-        g = relabel(g, seed_rng(seed, 2).permutation(g.n))
-    return g
+    k = int(config.get("orders", 0))
+    if not k:
+        return [g]
+    if fixed is None:
+        raise ValueError("'orders' needs a 'graph_seed' to fix the orders")
+    graphs = [relabel(g, seed_rng(fixed, 2 + i).permutation(g.n))
+              for i in range(k)]
+    first = seed % k
+    log(f"{k} vertex orders a cycle, from order {first}")
+    return graphs[first:] + graphs[:first]
 
 
 def enable_compile_cache() -> None:
@@ -156,7 +167,9 @@ class Run:
         self.trace = bool(trace)
         self.t_start = t_start
         self.compiles = CompileCounter()
-        self.graph = generate_graph(self.config, seed)
+        self.graphs = generate_graphs(self.config, seed)
+        # the readers' graph: every order has its sizes
+        self.graph = self.graphs[0]
         self.t_open = self.t_close = None
         self.window_compiles = None
         self.trace_dir = None
@@ -170,9 +183,12 @@ class Run:
         return self.jax.profiler.TraceAnnotation(f"bench.{name}")
 
     def done(self, count: int) -> bool:
-        """True once the window should close after ``count`` requests: the
-        first request that ends past ``--seconds``, or in a traced run the
+        """True once the window should close after ``count`` requests: at
+        the end of the first whole cycle over ``graphs`` that ends past
+        ``--seconds``, or in a traced run of the first that reaches the
         mix's ``trace_requests``."""
+        if count % len(self.graphs):
+            return False
         if self.trace:
             return count >= int(self.traffic["trace_requests"])
         return time.perf_counter() - self.t_open >= self.seconds

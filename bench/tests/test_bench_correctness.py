@@ -90,15 +90,15 @@ def alter_one_answer(colors, g):
     return colors
 
 
-def oneshot_fault(repro, kind):
-    """A coloring broken after the warm-up request."""
+def oneshot_fault(repro, kind, warmups):
+    """A coloring broken after the ``warmups`` warm-up requests."""
     def wrap(real):
         calls = [0]
 
         def color(g, *a, **kw):
             r = real(g, *a, **kw)
             calls[0] += 1
-            if calls[0] == 1:
+            if calls[0] <= warmups:
                 return r
             if kind == "answer_altered":
                 r.colors = alter_one_answer(r.colors, g)
@@ -116,9 +116,70 @@ def oneshot_fault(repro, kind):
                                   "never_answers"])
 @pytest.mark.parametrize("workload", CELLS)
 def test_oneshot_fault_is_not_correct(repro, workload, kind):
-    with oneshot_fault(repro, kind):
-        r = run(workload)
+    spec = small_spec(workload)
+    with oneshot_fault(repro, kind, warmups=spec["config"]["orders"]):
+        r = run(workload, spec)
     assert r["correct"] is False
     assert improper(r) > 0
     if kind == "never_answers":
         assert r["failed"] == r["attempted"]
+
+
+def orders(spec):
+    return spec["config"]["orders"]
+
+
+@pytest.mark.parametrize("trace", [False, True])
+@pytest.mark.parametrize("workload", CELLS)
+def test_a_window_holds_whole_cycles_of_the_orders(workload, trace):
+    spec = small_spec(workload)
+    spec["traffic"]["trace_requests"] = orders(spec) + 1
+    r = harness.execute(workload, SEED, SECONDS, trace,
+                        t_start=time.perf_counter(), spec=spec)
+    assert r["correct"] is True
+    assert r["attempted"] >= orders(spec) and r["attempted"] % orders(spec) == 0
+    if trace:  # the first whole cycle that reaches trace_requests
+        assert r["attempted"] == 2 * orders(spec)
+
+
+def _graph_key(g):
+    return g.row_offsets.tobytes(), g.col_indices.tobytes()
+
+
+@pytest.mark.parametrize("workload", CELLS)
+def test_seeds_color_the_same_orders_in_other_sequences(workload):
+    config = small_spec(workload)["config"]
+    k = config["orders"]
+    seeds = [SEED + i for i in range(k)]
+    runs = [[_graph_key(g) for g in harness.generate_graphs(config, s)]
+            for s in seeds]
+    assert all(len(keys) == k for keys in runs)
+    assert len(set(runs[0])) == k  # K distinct orders
+    assert all(sorted(keys) == sorted(runs[0]) for keys in runs)
+    assert len({tuple(keys) for keys in runs}) == k  # K distinct sequences
+
+
+@pytest.mark.parametrize("workload", CELLS)
+def test_nothing_compiles_in_the_window_after_the_warmup(repro, workload,
+                                                         monkeypatch):
+    runs, calls = [], []
+    real_run, real_color = harness.Run, repro.color
+
+    def keep(*a, **kw):
+        runs.append(real_run(*a, **kw))
+        return runs[-1]
+
+    def color(g, *a, **kw):
+        calls.append((_graph_key(g), time.perf_counter()))
+        return real_color(g, *a, **kw)
+
+    monkeypatch.setattr(harness, "Run", keep)
+    monkeypatch.setattr(repro, "color", color)
+    r = run(workload)
+    assert r["correct"] is True
+    (window_run,) = runs
+    assert len(window_run.graphs) == orders(small_spec(workload))
+    # set-up colors each order once, in the run's sequence
+    assert [key for key, t in calls if t < window_run.t_open] == \
+        [_graph_key(g) for g in window_run.graphs]
+    assert window_run.window_compiles["compiles"] == 0

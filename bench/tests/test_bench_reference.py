@@ -59,13 +59,25 @@ def test_relabel_renames_vertices_and_keeps_every_size():
 
 
 def test_a_fixed_draw_with_relabeling_gives_every_seed_one_graph():
-    cfg = {"generator": "rmat", "graph_seed": 3, "relabel": True,
+    # the same K relabelings of one fixed draw for every seed; the seed
+    # only rotates the sequence
+    cfg = {"generator": "rmat", "graph_seed": 3, "orders": 4,
            "graph": {"n": 1024, "avg_degree": 10.0, "abcd": [0.45, 0.15, 0.15, 0.25]}}
-    a, b = harness.generate_graph(cfg, 1), harness.generate_graph(cfg, 2**31 + 1)
-    assert a.entries == b.entries
-    assert np.array_equal(np.sort(a.degrees), np.sort(b.degrees))
-    assert not np.array_equal(a.col_indices, b.col_indices)
-    assert np.array_equal(harness.generate_graph(cfg, 1).col_indices, a.col_indices)
+    a, b = harness.generate_graphs(cfg, 1), harness.generate_graphs(cfg, 2**31 + 2)
+    assert len(a) == len(b) == 4
+    assert {g.entries for g in a + b} == {a[0].entries}
+    assert all(np.array_equal(np.sort(g.degrees), np.sort(a[0].degrees))
+               for g in a + b)
+    assert len({g.col_indices.tobytes() for g in a}) == 4
+    assert [g.col_indices.tobytes() for g in b] == \
+        [g.col_indices.tobytes() for g in a[1:] + a[:1]]
+    assert all(np.array_equal(x.col_indices, y.col_indices)
+               for x, y in zip(harness.generate_graphs(cfg, 1), a))
+    # without orders, the draw itself; orders need a fixed draw
+    one, = harness.generate_graphs(dict(cfg, orders=0), 1)
+    assert one.entries == a[0].entries
+    with pytest.raises(ValueError):
+        harness.generate_graphs(dict(cfg, graph_seed=None), 1)
 
 
 def test_rmat_rejects_probabilities_that_do_not_sum_to_one():

@@ -144,8 +144,9 @@ def test_readers_return_none_on_a_program_without_spans(monkeypatch, ring,
 
 def test_a_traced_run_reports_the_span_metrics():
     """A traced run of the cell at a small size on the host: the harness
-    finds the readers by name and each reads the window's request.  The
-    host backend has no device plane, so ``host_idle_s`` reads nothing."""
+    finds the readers by name and each reads the mean over the window's
+    requests, one whole cycle of the orders.  The host backend has no
+    device plane, so ``host_idle_s`` reads nothing."""
     import time
 
     from bench.tests.test_bench_correctness import SEED, small_spec
@@ -157,11 +158,16 @@ def test_a_traced_run_reports_the_span_metrics():
     r = harness.execute("rmat-g.oneshot", SEED, 0.3, True,
                         t_start=time.perf_counter(), spec=spec)
     m = {k: v["value"] for k, v in r["metrics"].items()}
-    assert r["correct"] and r["attempted"] == 1
+    k = spec["traffic"]["trace_requests"]
+    assert k == spec["config"]["orders"]
+    assert r["correct"] and r["attempted"] == k
     assert "host_idle_s.oneshot" not in m
-    (root, desc), = ps.window_requests({"layer": {"requests": 1}})
-    assert m["tail_vertices.oneshot"] == sum(
-        e.meta["vertices"] for e in desc if e.name == "serial_tail")
+    requests = ps.window_requests({"layer": {"requests": k}})
+    assert len(requests) == k
+    assert m["tail_vertices.oneshot"] == pytest.approx(sum(
+        e.meta["vertices"] for _, desc in requests for e in desc
+        if e.name == "serial_tail") / k)
     assert m["tail_s.oneshot"] + m["superstep_s.oneshot"] \
-        + m["host_s.oneshot"] == pytest.approx(root.duration)
+        + m["host_s.oneshot"] == pytest.approx(
+            sum(root.duration for root, _ in requests) / k)
     assert m["superstep_s.oneshot"] > 0 and m["host_s.oneshot"] > 0

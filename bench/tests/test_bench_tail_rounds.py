@@ -62,7 +62,7 @@ def test_tail_rounds_reads_none_without_requests(monkeypatch, ring, drop):
 def test_a_traced_run_reports_tail_rounds():
     """A traced run of the cell at a small size on the host: the harness
     finds the reader by name, and it reads the rounds of the window's
-    request's tail."""
+    requests' tails, one whole cycle of the orders."""
     from bench.tests.test_bench_correctness import SEED, small_spec
 
     spec = small_spec("rmat-g.oneshot")
@@ -71,9 +71,12 @@ def test_a_traced_run_reports_tail_rounds():
     assert len(spec["per_layer"]) == 1
     r = harness.execute("rmat-g.oneshot", SEED, 0.3, True,
                         t_start=time.perf_counter(), spec=spec)
-    assert r["correct"] and r["attempted"] == 1
-    (root, desc), = ps.window_requests({"layer": {"requests": 1}})
-    tails = [e for e in desc if e.name == "serial_tail"]
+    k = spec["traffic"]["trace_requests"]
+    assert r["correct"] and r["attempted"] == k == spec["config"]["orders"]
+    requests = ps.window_requests({"layer": {"requests": k}})
+    tails = [e for _, desc in requests for e in desc
+             if e.name == "serial_tail"]
+    assert len(requests) == k
     assert tails and all(e.meta["rounds"] >= 1 for e in tails)
-    assert r["metrics"]["tail_rounds.oneshot"]["value"] == sum(
-        e.meta["rounds"] for e in tails)
+    assert r["metrics"]["tail_rounds.oneshot"]["value"] == pytest.approx(
+        sum(e.meta["rounds"] for e in tails) / k)
