@@ -49,7 +49,7 @@ from jax import lax
 
 from repro.api import register
 from repro.core.csr import CSRGraph, DeviceCSR, auto_tile_thresholds, next_pow2
-from repro.core.firstfit import FF_FUNCS
+from repro.core.firstfit import FF_FUNCS, bitset_words
 from repro.core.heuristics import conflict_lose_flags, conflict_lose_lanes
 from repro.obs.spans import SpanRecorder, span
 from repro.obs.trace import (
@@ -153,6 +153,15 @@ def gather_rows(adj: jax.Array, ids: jax.Array, sentinel: int | None = None) -> 
     return jnp.where((ids < n)[:, None], rows, fill)
 
 
+def _firstfit(kind: str, nc, ff_words: int | None = None):
+    """``FF_FUNCS[kind](nc)``; the bitset at ``ff_words`` words when given
+    (the same answers where every color of ``nc`` lies below
+    ``32 * ff_words``, see ``firstfit_bitset``)."""
+    if kind == "bitset" and ff_words:
+        return FF_FUNCS["bitset"](nc, ff_words)
+    return FF_FUNCS[kind](nc)
+
+
 def take_tile(x: jax.Array, rows: jax.Array) -> jax.Array:
     """``x[rows]`` for a ``(w, W)`` tile of in-bounds ids.
 
@@ -192,6 +201,31 @@ def cr_flags(adj, deg_ext, colors_ext, ids, heuristic: str,
 
         return conflict_tpu(ids, rows, my_c, nc, my_d, nd, heuristic)
     return conflict_lose_flags(ids, rows, my_c, nc, my_d, nd, heuristic)
+
+
+SCATTER_BLOCK = 1 << 21  # most elements one scatter of a state array writes
+
+
+def blocked_scatter(x: jax.Array, idx: jax.Array, vals, *, add: bool = False):
+    """``x.at[idx].set(vals)`` (``.add`` with ``add``), indices past ``x``
+    dropped, as one scatter per block of ``SCATTER_BLOCK`` elements.
+
+    The same values, each index landing in its one block.  The v5e
+    compiler takes about 8 s over one scatter of 96k updates into 2^22
+    elements and under a second over blocks of 2^21; a state of up to
+    2^21 elements (rmat-g's 2^20 + 1) is one block, the plain scatter.
+    """
+    def one(part, i):
+        upd = part.at[i]
+        return upd.add(vals, mode="drop") if add else upd.set(vals, mode="drop")
+
+    n = x.shape[0]
+    if n <= SCATTER_BLOCK:
+        return one(x, idx)
+    return jnp.concatenate([
+        one(x[lo:lo + SCATTER_BLOCK], jnp.where(
+            (idx >= lo) & (idx < lo + SCATTER_BLOCK), idx - lo, SCATTER_BLOCK))
+        for lo in range(0, n, SCATTER_BLOCK)])
 
 
 def compact(ids: jax.Array, flags: jax.Array, sentinel: int):
@@ -278,7 +312,8 @@ def ragged_superstep(rows_fn, deg_ext, colors_ext, wl, *,
                      heuristic: str = "degree", kind: str = "bitset",
                      use_kernel: bool = False, coarsen: int = 1,
                      colors_read=None, pack_degrees: bool = False,
-                     provider=None, width: int | None = None):
+                     provider=None, width: int | None = None,
+                     ff_words: int | None = None):
     """One rotated super-step: ConflictResolve + FirstFit + compaction.
 
     ``rows_fn(ids) -> (w, W)`` provides the sentinel-padded neighbor tile —
@@ -299,6 +334,10 @@ def ragged_superstep(rows_fn, deg_ext, colors_ext, wl, *,
     gather.  Callers enable it when both fields provably fit 15 bits (colors
     are bounded by the gather width + 1).  Packed or not, the arithmetic is
     exact, so results are bit-identical either way.
+
+    ``ff_words`` (static) caps the bitset FirstFit's words; the caller
+    makes it cover every color the step can read (``bitset_words``), so
+    the colors are those of the full-width bitset.
 
     ``use_kernel="csr"`` (backend="pallas-csr", DESIGN.md §18) routes the
     step through the CSR-resident fused kernel when ``provider`` is a
@@ -330,7 +369,7 @@ def ragged_superstep(rows_fn, deg_ext, colors_ext, wl, *,
             valid = ids < n
             need = need & valid
             new_c = jnp.where(valid, new_c, 0).astype(colors_ext.dtype)
-            colors_ext = colors_ext.at[ids].set(new_c)
+            colors_ext = blocked_scatter(colors_ext, ids, new_c)
             read = colors_ext
             need_parts.append(need)
             continue
@@ -356,11 +395,11 @@ def ragged_superstep(rows_fn, deg_ext, colors_ext, wl, *,
             # lanes I beat are provably recoloring too — refit as if cleared
             # (the classic engine's clear-then-refit dynamics, in one pass)
             ff_nc = jnp.where(same & ~lose_lane, 0, nc)
-            new_c = jnp.where(need, FF_FUNCS[kind](ff_nc), my_c)
+            new_c = jnp.where(need, _firstfit(kind, ff_nc, ff_words), my_c)
         valid = ids < n
         need = need & valid
         new_c = jnp.where(valid, new_c, 0).astype(colors_ext.dtype)
-        colors_ext = colors_ext.at[ids].set(new_c)
+        colors_ext = blocked_scatter(colors_ext, ids, new_c)
         read = colors_ext  # later chunks observe earlier chunks' writes
         need_parts.append(need)
     need = jnp.concatenate(need_parts) if len(need_parts) > 1 else need_parts[0]
@@ -509,8 +548,29 @@ def provider_tail(provider, colors_ext, wl, *, kind="bitset"):
 # the longest predecessor chain in the order.
 
 ROUND_CAP_FLOOR = 256  # fewest lanes a round dispatches per class (jit keys)
+# most cells that floor may pad a class to: 256 lanes of rmat-g's widest
+# tail class (376) fit, a hub class 162,605 wide gets one lane
+ROUND_FLOOR_CELLS = 1 << 17
 TAIL_MIN_WIDTH = 16    # narrowest tail class (jit keys)
 PREFIX_BLOCK = 1024    # lanes per block of the blocked prefix count
+
+
+def _round_lanes(count: int, width: int | None) -> int:
+    """Lanes a round dispatches for ``count`` frontier vertices of a class
+    ``width`` wide: a power of two, at least ``ROUND_CAP_FLOOR`` halved
+    until the floor's tile holds at most ``ROUND_FLOOR_CELLS`` cells."""
+    floor = ROUND_CAP_FLOOR
+    while width is not None and floor > 1 and floor * width > ROUND_FLOOR_CELLS:
+        floor //= 2
+    return max(floor, next_pow2(count))
+
+
+def _pow2_widths(deg, floor: int, cap: int):
+    """Each row length rounded up to a power of two, at least ``floor``
+    and at most ``cap``: a tile at such widths gathers fewer than two
+    lanes per entry (``cap`` aside)."""
+    return np.minimum(
+        1 << np.ceil(np.log2(np.maximum(deg, floor))).astype(int), cap)
 
 
 def _tail_tile(provider, ids, width):
@@ -605,12 +665,14 @@ def provider_tail_setup(provider, colors_ext, tail_wl, members, *, widths,
 def provider_tail_frontier(state, cnt, cls, *, classes):
     """Per-class running counts of the uncolored tail vertices with no
     uncolored predecessor: row ``k`` of the ``(classes, n)`` counts numbers
-    class ``k``'s frontier; its last column is the class count."""
+    class ``k``'s frontier.  Also returns the class counts (the last
+    column) followed by the largest color of ``state``, which bounds the
+    colors the round's FirstFit reads."""
     n = state.shape[0] - 1
     ready = (state[:n] == -1) & (cnt[:n] == 0)
     member = cls[None, :n] == jnp.arange(classes, dtype=jnp.int32)[:, None]
     csum = _prefix_sum(ready[None, :] & member)
-    return csum, csum[:, -1]
+    return csum, jnp.concatenate([csum[:, -1], jnp.max(state)[None]])
 
 
 def _nth_true(csum, cap):
@@ -643,20 +705,22 @@ def _nth_true(csum, cap):
     return lo
 
 
-@partial(jax.jit, static_argnames=("cap", "width", "kind"))
-def provider_tail_round(provider, state, cnt, csum, k, *, cap, width, kind):
+@partial(jax.jit, static_argnames=("cap", "width", "kind", "ff_words"))
+def provider_tail_round(provider, state, cnt, csum, k, *, cap, width, kind,
+                        ff_words=None):
     """Color class ``k``'s frontier (at most ``cap`` vertices) by FirstFit
-    and take them off their successors' predecessor counters."""
+    and take them off their successors' predecessor counters.
+    ``ff_words`` caps the bitset's words (``ragged_superstep``)."""
     n = state.shape[0] - 1
     ids = _nth_true(csum[k], cap)
     rows = _tail_tile(provider, ids, width)
     nc = take_tile(state, rows)
     valid = ids < n
-    c = jnp.where(valid, FF_FUNCS[kind](jnp.maximum(nc, 0).T), 0)
-    state = state.at[ids].set(c.astype(state.dtype))
+    c = jnp.where(valid, _firstfit(kind, jnp.maximum(nc, 0).T, ff_words), 0)
+    state = blocked_scatter(state, ids, c.astype(state.dtype))
     # an uncolored tail lane of a frontier vertex is one of its successors
     succ = (nc < 0) & valid
-    cnt = cnt.at[jnp.where(succ, rows, n + 1)].add(-1, mode="drop")
+    cnt = blocked_scatter(cnt, jnp.where(succ, rows, n + 1), -1, add=True)
     return state, cnt
 
 
@@ -666,20 +730,21 @@ def _tail_classes(provider, tail_ids):
     A ``DeviceCSR`` tail splits by row length rounded up to a power of two
     (at least ``TAIL_MIN_WIDTH``, at most the provider's width), so a
     tile's lanes are at most twice its entries; the engine's degree
-    classes are wider (rmat-g's reach from degree 33 to 376).  Other
-    providers gather their full width whatever is asked: one class.
+    classes are wider (rmat-g's reach from degree 33 to 376).  A round
+    dispatches ``_round_lanes`` lanes of a class, so its cells follow the
+    frontier however wide the class: a hub is one lane at its own width.
+    Other providers gather their full width whatever is asked: one class.
     """
     if not isinstance(provider, DeviceCSR):
         return [tail_ids], [None]
     deg = np.asarray(provider.deg_ext)[tail_ids]
-    widths = np.minimum(
-        1 << np.ceil(np.log2(np.maximum(deg, TAIL_MIN_WIDTH))).astype(int),
-        provider.max_width)
+    widths = _pow2_widths(deg, TAIL_MIN_WIDTH, provider.max_width)
     ws = sorted(set(widths.tolist()), reverse=True)
     return [tail_ids[widths == w] for w in ws], ws
 
 
-def round_tail(provider, colors_ext, tail_wl, tail_ids, kind="bitset"):
+def round_tail(provider, colors_ext, tail_wl, tail_ids, kind="bitset",
+               meta=None):
     """The serial tail's coloring of ``tail_wl``, by dependency rounds.
 
     Bit-identical to ``serial_tail_step(provider.row1, colors_ext,
@@ -689,15 +754,20 @@ def round_tail(provider, colors_ext, tail_wl, tail_ids, kind="bitset"):
     frontier vertex reads exactly the colors the sequential loop would
     show it (see the comment above ``ROUND_CAP_FLOOR``).  ``tail_ids``
     holds the real entries of ``tail_wl`` on the host; each round
-    dispatches every class's frontier (``_tail_classes``) at a power-of-two
-    lane count of at least ``ROUND_CAP_FLOOR``.  One count readback per
-    round, as a super-step.  Returns ``(colors_ext, rounds)``.
+    dispatches every class's frontier (``_tail_classes``) at
+    ``_round_lanes`` lanes.  One readback per round, as a super-step: the
+    frontier counts and the largest color, which sets the words of the
+    round's bitset FirstFit (``bitset_words``: a frontier vertex reads only
+    colors already in the state, so its answer is at most that color + 1).
+    Returns ``(colors_ext, rounds)``; a ``meta`` dict (the caller's span's)
+    receives ``cells``, the lanes times widths dispatched, and
+    ``ff_words``, the most bitset words dispatched.
     """
     n = colors_ext.shape[0] - 1
     members, widths = _tail_classes(provider, np.asarray(tail_ids, np.int32))
     padded = []
-    for m in members:
-        buf = np.full(max(ROUND_CAP_FLOOR, next_pow2(m.size)), n, np.int32)
+    for m, w in zip(members, widths):
+        buf = np.full(_round_lanes(m.size, w), n, np.int32)
         buf[:m.size] = m
         padded.append(jnp.asarray(buf))
     widths = tuple(widths)
@@ -709,30 +779,39 @@ def round_tail(provider, colors_ext, tail_wl, tail_ids, kind="bitset"):
                                           tuple(padded), widths=widths,
                                           by_entries=by_entries)
     todo = sum(int(m.size) for m in members)
-    rounds = 0
+    rounds = cells = most_words = 0
     while todo:
         csum, counts = provider_tail_frontier(state, cnt, cls,
                                               classes=len(members))
-        counts = np.asarray(counts).tolist()
+        *counts, top = np.asarray(counts).tolist()
         if not any(counts):
             raise ValueError(
                 f"serial tail: {todo} vertices wait on predecessors that "
                 "never take a color; the provider's rows are not symmetric")
+        words = bitset_words(top) if kind == "bitset" else None
         for k, c in enumerate(counts):
             if c:
+                cap = _round_lanes(c, widths[k])
                 state, cnt = provider_tail_round(
-                    provider, state, cnt, csum, k,
-                    cap=max(ROUND_CAP_FLOOR, next_pow2(c)), width=widths[k],
-                    kind=kind)
+                    provider, state, cnt, csum, k, cap=cap, width=widths[k],
+                    kind=kind, ff_words=words)
+                if widths[k] is not None:
+                    cells += cap * widths[k]
+                    if words:
+                        most_words = max(most_words, min(
+                            words, (widths[k] + 32) // 32))
         todo -= sum(counts)
         rounds += 1
+    if meta is not None:
+        meta.update(cells=cells, ff_words=most_words)
     return state, rounds
 
 
 def _dispatch_tail(provider, colors_ext, wl, tail_ids, *, kind, use_kernel,
-                   width):
+                   width, meta=None):
     """Run the serial tail over ``wl`` (``order_tail`` order; ``tail_ids``
-    its real entries on the host): ``(colors_ext, rounds)``.
+    its real entries on the host): ``(colors_ext, rounds)``; ``meta`` as
+    ``round_tail``'s.
 
     ``use_kernel="csr"`` with a ``DeviceCSR`` provider runs the §18 grid=1
     sequential kernel (one dispatch, live aliased color state, one step
@@ -747,16 +826,19 @@ def _dispatch_tail(provider, colors_ext, wl, tail_ids, *, kind, use_kernel,
         return serial_tail_csr_tpu(
             provider.row_starts, provider.col_padded, provider.deg_ext,
             colors_ext, wl, width), len(tail_ids)
-    return round_tail(provider, colors_ext, wl, tail_ids, kind)
+    return round_tail(provider, colors_ext, wl, tail_ids, kind, meta=meta)
 
 
 def _tiled_superstep(provider, deg_ext, colors_ext, wls, *, widths, heuristic,
-                     kind, use_kernel, chunks, pack_degrees=False):
+                     kind, use_kernel, chunks, pack_degrees=False,
+                     ff_words=None):
     """One degree-tiled super-step: every class sub-step in one computation.
 
     Classes gather at their own tile widths but all speculate against the
     iteration-start snapshot (writes are disjoint), so the result is
     bit-identical to a single full-width step over the union worklist.
+    ``ff_words`` caps every class's bitset words (``ragged_superstep``).
+    Returns ``(colors_ext, worklists, counts, largest color)``.
     """
     snapshot = colors_ext
     K = len(wls)
@@ -769,16 +851,16 @@ def _tiled_superstep(provider, deg_ext, colors_ext, wls, *, widths, heuristic,
             coarsen=chunks[k],
             colors_read=None if K == 1 else snapshot,
             pack_degrees=pack_degrees,
-            provider=provider, width=widths[k],
+            provider=provider, width=widths[k], ff_words=ff_words,
         )
         new_wls.append(wl_k)
         counts.append(cnt_k)
-    return colors_ext, tuple(new_wls), tuple(counts)
+    return colors_ext, tuple(new_wls), tuple(counts), jnp.max(colors_ext)
 
 
 provider_tiled_superstep = partial(
     jax.jit, static_argnames=("widths", "heuristic", "kind", "use_kernel",
-                              "chunks", "pack_degrees")
+                              "chunks", "pack_degrees", "ff_words")
 )(_tiled_superstep)
 
 
@@ -855,8 +937,16 @@ def run_ragged_engine(
             f"pack_degrees=True with tail_width={tail_width}: degrees must "
             f"stay < {PACKED_GATHER_MAX_DEG} to fit the packed gather word "
             "(color | deg << 16, int32); rerun with pack_degrees=False")
+    sizes = [int(c.shape[0]) for c in classes]
+    # a class dispatches at least two lanes (a one-vertex class gets the
+    # inert sentinel as its second): the v5e compiler takes minutes over a
+    # step that holds a one-row tile of a wide class beside a large narrow
+    # class (a hub row 162,605 wide beside 3.15M rows of 8: about 180 s,
+    # with two rows 11 s)
+    classes = [np.append(np.asarray(c), n).astype(np.int32) if size == 1
+               else c for c, size in zip(classes, sizes)]
     caps0 = [int(c.shape[0]) for c in classes]
-    counts_init = (caps0 if class_counts is None
+    counts_init = (sizes if class_counts is None
                    else [int(c) for c in class_counts])
     boot_iters = 0
     if colors_init is not None:
@@ -903,6 +993,9 @@ def run_ragged_engine(
     rows = []  # (§16) one telemetry row per super-step when tracing
     if trace_cap and boot_iters:
         rows.append((n, 0, n, 1, 0, 0, 0, 0))
+    # the largest color the next step's snapshot holds: it bounds the
+    # words of the step's bitset FirstFit (``bitset_words``)
+    top = int(jnp.max(colors_ext))
     with span("superstep_loop", mode=mode):
         while total > 0 and iters < max_iters:
             if tail_enabled and total <= tail_threshold:
@@ -914,7 +1007,7 @@ def run_ragged_engine(
             sliced, chunk_l = [], []
             step_cells = 0
             for k in range(K):
-                cap = min(next_pow2(max(counts[k], 1)), caps[k])
+                cap = min(next_pow2(max(counts[k], 2)), caps[k])
                 sliced.append(wls[k][:cap])
                 chunk_l.append(max(1, math.ceil(cap / coarsen_lanes))
                                if coarsen_lanes else coarsen)
@@ -923,15 +1016,26 @@ def run_ragged_engine(
                     padded += cap * acc_widths[k]
                     cells_k[k] += cap * acc_widths[k]
                     step_cells += cap * acc_widths[k]
+            words = None
+            if kind == "bitset" and not use_kernel:
+                # a chunk after the first also reads the colors the step's
+                # earlier chunks wrote, each at most one above what they read
+                words = bitset_words(
+                    top, sum(chunk_l) if max(chunk_l) > 1 else 1)
+            full = [(a + 32) // 32 for a in acc_widths]  # (W + 1) / 32 words
+            step_words = max((min(words or f, f)
+                              for f, c in zip(full, counts) if c), default=0)
             # the count reads wait for the device: the span holds the step
-            with span("superstep", step=iters, live=total, cells=step_cells):
-                colors_ext, new_wls, cnts = provider_tiled_superstep(
+            with span("superstep", step=iters, live=total, cells=step_cells,
+                      ff_words=step_words):
+                colors_ext, new_wls, cnts, top = provider_tiled_superstep(
                     provider, deg_ext, colors_ext, tuple(sliced),
                     widths=tuple(tile_widths), heuristic=heuristic, kind=kind,
                     use_kernel=use_kernel, chunks=tuple(chunk_l),
-                    pack_degrees=pack_degrees,
+                    pack_degrees=pack_degrees, ff_words=words,
                 )
                 counts = [int(c) for c in cnts]
+                top = int(top)
             wls = list(new_wls)
             iters += 1
             new_total = sum(counts)
@@ -957,7 +1061,7 @@ def run_ragged_engine(
             tail_wl = order_tail(jnp.asarray(tail_np), deg_ext)
             colors_ext, sp.meta["rounds"] = _dispatch_tail(
                 provider, colors_ext, tail_wl, tail_ids, kind=kind,
-                use_kernel=use_kernel, width=tail_width)
+                use_kernel=use_kernel, width=tail_width, meta=sp.meta)
             # the readback below waits for this buffer anyway: the span
             # ends when the device is done with the tail
             colors_ext.block_until_ready()
@@ -1016,7 +1120,7 @@ def _fused_spec_loop(provider, deg_ext, colors_ext, wls, counts, thr, *,
     def body(state):
         colors_ext, wls, counts, it, work = state[:5]
         prev = total_of(counts)
-        colors_ext, new_wls, new_counts = _tiled_superstep(
+        colors_ext, new_wls, new_counts, _ = _tiled_superstep(
             provider, deg_ext, colors_ext, wls,
             widths=tile_widths, heuristic=heuristic, kind=kind,
             use_kernel=use_kernel, chunks=chunks, pack_degrees=pack_degrees,
@@ -1099,7 +1203,7 @@ def _run_ragged_fused(
             sp.meta["vertices"] = int(tail_wl.shape[0])
             colors_ext, sp.meta["rounds"] = _dispatch_tail(
                 provider, colors_ext, tail_wl, tail_ids, kind=kind,
-                use_kernel=use_kernel, width=tail_width)
+                use_kernel=use_kernel, width=tail_width, meta=sp.meta)
             colors_ext.block_until_ready()  # the readback waits for it anyway
         work_items += n if stalled and stall_serializes_all else total
         tail_cells = int(tail_wl.shape[0]) * tail_width
@@ -1230,6 +1334,11 @@ def _prepare(g: CSRGraph, buckets):
     return adjs, deg_ext, classes
 
 
+# an automatic class may gather up to this many lanes per adjacency entry
+# before it is split; rmat-g's widest class (degree 33-376) gathers 7.8
+PLAN_LANES_PER_ENTRY = 8
+
+
 def _resolve_classes(degrees: np.ndarray, buckets, tiling):
     """(classes, widths) for the degree-tiled dispatch, wide-first order.
 
@@ -1239,6 +1348,15 @@ def _resolve_classes(degrees: np.ndarray, buckets, tiling):
     histogram of the GATHERED side (the original graph's, G²'s, or a
     conflict graph's — shared with ``repro.d2``); degree-0 vertices join the
     narrowest class, empty classes are dropped.
+
+    Under ``"auto"`` a class whose tile (members x width) holds more than
+    ``PLAN_LANES_PER_ENTRY`` lanes per adjacency entry of its members is
+    split by row length rounded up to a power of two (``_pow2_widths``, as
+    the round tail's classes), so each part gathers fewer than two lanes
+    per entry: a hub of degree 162,605 among vertices of degree 513 is
+    then one lane at its own width, not one of 35,443.  The colors do not
+    change: the tiled super-step's classes all read one snapshot, so it
+    colors as one class would (``_tiled_superstep``).
     """
     degrees = np.asarray(degrees)
     n = int(degrees.size)
@@ -1263,11 +1381,30 @@ def _resolve_classes(degrees: np.ndarray, buckets, tiling):
     zero = np.where(degrees == 0)[0].astype(np.int32)
     if zero.size:  # degree-0 vertices take color 1 trivially: narrowest class
         classes[0] = np.concatenate([zero, classes[0]])
+    if tiling == "auto" and not buckets:
+        classes, widths = _split_wide_classes(degrees, classes, widths)
     order = np.argsort([-w for w in widths], kind="stable")
     pairs = [(classes[i], widths[i]) for i in order if classes[i].size]
     if not pairs:
         return [np.arange(n, dtype=np.int32)], [dmax]
     return [p[0] for p in pairs], [p[1] for p in pairs]
+
+
+def _split_wide_classes(degrees, classes, widths):
+    """``_resolve_classes``' split of the classes that gather more than
+    ``PLAN_LANES_PER_ENTRY`` lanes per entry into power-of-two widths."""
+    out_c, out_w = [], []
+    for ids, width in zip(classes, widths):
+        deg = degrees[ids]
+        if ids.size * width <= PLAN_LANES_PER_ENTRY * int(deg.sum()):
+            out_c.append(ids)
+            out_w.append(width)
+            continue
+        sub = _pow2_widths(deg, 1, width)
+        for w in np.unique(sub):
+            out_c.append(ids[sub == w])
+            out_w.append(int(w))
+    return out_c, out_w
 
 
 def _graph_device_cache(g, key: str, build):

@@ -6,6 +6,12 @@ colors of up to ``W`` neighbors per worklist vertex, 0 meaning
 ``[1, W+1]`` per row.  Greedy guarantees a free color exists in that range
 (W neighbors can forbid at most W of the W+1 candidates).
 
+The answer is also at most the row's largest color + 1, so a bitset of
+``bitset_words(C)`` words gives the same answer as the full ``(W+1)/32``
+whenever no row holds a color above C: the word count follows the colors
+in play, not the row width (a hub row of 162,605 lanes in a graph of 282
+colors needs 16 words, not 5,082).
+
 Variants (see DESIGN.md §3 for the CUDA→TPU mapping):
 
 * ``scan``   — the paper's baseline colorMask: scatter forbidden counts into a
@@ -26,7 +32,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["firstfit_scan", "firstfit_sort", "firstfit_bitset", "FF_FUNCS", "ffs_u32"]
+from repro.core.csr import next_pow2
+
+__all__ = ["firstfit_scan", "firstfit_sort", "firstfit_bitset", "FF_FUNCS",
+           "FF_MIN_WORDS", "bitset_words", "ffs_u32"]
+
+# fewest bitset words a bounded FirstFit asks for: the word count is a jit
+# key, and with 16 words one program serves every graph of up to 510 colors
+FF_MIN_WORDS = 16
 
 
 def firstfit_scan(neigh_colors: jax.Array) -> jax.Array:
@@ -79,15 +92,35 @@ def ffs_u32(x: jax.Array) -> jax.Array:
     return jnp.where(x == 0, jnp.uint32(32), tz).astype(jnp.int32)
 
 
-def firstfit_bitset(neigh_colors: jax.Array) -> jax.Array:
-    """The paper's bitset FirstFit: bit words + find-first-set."""
+def bitset_words(max_color: int, extra: int = 1) -> int:
+    """Static bitset words for tiles whose colors are at most ``max_color``
+    and whose rows may each see up to ``extra - 1`` colors more (one per
+    earlier chunk of a chunked step): their answers are at most
+    ``max_color + extra``, which ``32 * words`` bits always hold.  A power
+    of two, at least ``FF_MIN_WORDS``, so few programs serve every count;
+    ``firstfit_bitset`` caps it at the tile's own ``(W + 1) / 32``."""
+    return max(FF_MIN_WORDS, next_pow2(-(-(max_color + extra + 1) // 32)))
+
+
+def firstfit_bitset(neigh_colors: jax.Array,
+                    nwords: int | None = None) -> jax.Array:
+    """The paper's bitset FirstFit: bit words + find-first-set.
+
+    ``nwords`` (static) caps the bitset below the full ``(W + 1) / 32``
+    words.  The answer is the same whenever every row's answer fits in
+    ``32 * nwords`` bits, which holds when no row holds a color of
+    ``32 * nwords`` or more (an answer is at most its row's largest
+    color + 1): colors past the last word are left out, and the smallest
+    free color lies before them.  ``bitset_words`` gives such a bound.
+    """
     w, W = neigh_colors.shape
     nbits = W + 1
-    nwords = (nbits + 31) // 32
+    full = (nbits + 31) // 32
+    nwords = full if nwords is None else min(int(nwords), full)
     words = _forbidden_words(neigh_colors, nwords)
     # forbid phantom candidates beyond W+1 so ffs never exceeds the greedy bound
     tail = nwords * 32 - nbits
-    if tail:
+    if tail > 0:
         pad_mask = jnp.uint32(((1 << tail) - 1) << (32 - tail))
         words = words.at[:, nwords - 1].set(words[:, nwords - 1] | pad_mask)
     free = ~words

@@ -229,8 +229,9 @@ def test_stalled_warm_start_tail_matches_the_sequential_tail(monkeypatch,
 
     round_tail, checked = C.round_tail, []
 
-    def checking(provider, colors_ext, wl, tail_ids, kind="bitset"):
-        out, rounds = round_tail(provider, colors_ext, wl, tail_ids, kind)
+    def checking(provider, colors_ext, wl, tail_ids, kind="bitset", **kw):
+        out, rounds = round_tail(provider, colors_ext, wl, tail_ids, kind,
+                                 **kw)
         want = C.provider_tail(provider, colors_ext, wl, kind=kind)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
         checked.append(rounds)

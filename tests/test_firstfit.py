@@ -6,9 +6,12 @@ module's coverage survives environments without hypothesis installed.
 """
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.firstfit import (
     FF_FUNCS,
+    FF_MIN_WORDS,
+    bitset_words,
     ffs_u32,
     firstfit_bitset,
     firstfit_scan,
@@ -50,6 +53,40 @@ def test_firstfit_ignores_uncolored_and_huge():
     nc = jnp.asarray(np.array([[0, 0, 999, 2]], dtype=np.int32))
     for fn in FF_FUNCS.values():
         assert int(fn(nc)[0]) == 1
+
+
+@pytest.mark.parametrize("W", [1, 32, 100, 600, 4000])
+@pytest.mark.parametrize("top", [0, 31, 300, 700])
+@pytest.mark.parametrize("chunks", [1, 3])
+def test_bounded_bitset_matches_the_full_bitset(W, top, chunks):
+    """The bitset at ``bitset_words(top, chunks)`` words answers as the
+    full-width one on tiles whose colors reach ``top + chunks - 1`` (the
+    last chunk of a chunked step): random rows, and rows that hold every
+    color up to their width or to that bound, whose answer is the last."""
+    rng = np.random.default_rng(W * 7919 + top * 31 + chunks)
+    hi = top + chunks - 1
+    nc = rng.integers(0, hi + 1, size=(48, W)).astype(np.int32)
+    full_rows = np.zeros((3, W), np.int32)
+    for i, k in enumerate((hi, hi - 1, W)):
+        k = max(0, min(k, W, hi))
+        full_rows[i, :k] = rng.permutation(np.arange(1, k + 1))
+    nc = jnp.asarray(np.concatenate([nc, full_rows]))
+    words = bitset_words(top, chunks)
+    assert words >= FF_MIN_WORDS and words & (words - 1) == 0
+    assert 32 * words >= top + chunks
+    want = np.asarray(firstfit_bitset(nc))
+    np.testing.assert_array_equal(np.asarray(firstfit_bitset(nc, words)),
+                                  want)
+    assert want.max() <= hi + 1
+
+
+def test_too_few_bitset_words_miss_the_answer():
+    """The bound is what keeps the cap exact: a row holding colors 1..40
+    needs two words, and one word cannot see its answer, 41."""
+    nc = jnp.asarray(np.arange(1, 41, dtype=np.int32)[None, :])
+    assert int(firstfit_bitset(nc)[0]) == 41
+    assert int(firstfit_bitset(nc, 2)[0]) == 41
+    assert int(firstfit_bitset(nc, 1)[0]) != 41
 
 
 def test_ffs_u32():

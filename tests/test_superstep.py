@@ -22,7 +22,9 @@ from repro.core import (
     csr_from_edges,
     is_valid_coloring,
     num_colors,
+    next_pow2,
 )
+from repro.core.firstfit import FF_MIN_WORDS
 from repro.core.serial import greedy_serial
 from repro.graphs import build_graph, erdos_renyi, grid2d, power_law, rmat
 from repro.kernels.superstep.csr_kernel import (
@@ -643,6 +645,172 @@ def test_round_tail_refuses_rows_that_are_not_symmetric():
     with pytest.raises(ValueError, match="not symmetric"):
         C.round_tail(C.DenseRows(adj), colors_ext, wl,
                      np.arange(3, dtype=np.int32))
+
+
+# --------------------------------------------------------------------------
+# hubs past the packed gather: planning, FirstFit and the tail bounded
+# --------------------------------------------------------------------------
+
+GRAPH500 = (0.57, 0.19, 0.19, 0.05)
+
+
+def _kron_with_hub(scale, hub, seed=0):
+    """A Graph500 Kronecker graph of 2^scale vertices (edge factor 16) and
+    one more vertex joined to the first ``hub`` vertices (leaves added as
+    needed): a star hub of degree ``hub``."""
+    g0 = rmat(2**scale, 32.0, GRAPH500, seed=seed)
+    src, dst = g0.edges()
+    n = max(g0.n, hub) + 1
+    return csr_from_edges(n, np.concatenate([src, np.full(hub, n - 1)]),
+                          np.concatenate([dst, np.arange(hub)]))
+
+
+def _improper(g, colors):
+    """``bench/reference.py``'s count, independent of the program."""
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "bench" / "reference.py"
+    spec = importlib.util.spec_from_file_location("bench_reference", path)
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    src, dst = g.edges()
+    return ref.improper_vertices(g.n, src, dst, colors)
+
+
+@pytest.fixture(scope="module")
+def hub_run():
+    """SCALE 12 plus a star hub of degree 33,000 through ``repro.color``."""
+    import repro
+    from repro.obs.spans import SpanRecorder
+
+    g = _kron_with_hub(12, 33000)
+    with SpanRecorder() as rec:
+        r = repro.color(g, "data_driven")
+    return g, r, rec.events
+
+
+def test_hub_graph_colors_as_the_largest_degree_first_greedy(hub_run):
+    """Past the packed gather's 2^15 (colors and degrees gathered apart),
+    the coloring is proper, and with the stall handing every vertex to the
+    tail it is the host's largest-degree-first greedy, vertex for vertex."""
+    g, r, events = hub_run
+    assert g.max_degree > 32767 and not C._packed_gather_ok(g.max_degree)
+    assert _improper(g, r.colors) == 0
+    tails = [e.meta for e in events if e.name == "serial_tail"]
+    assert len(tails) == 1 and tails[0]["stalled"]
+    assert tails[0]["vertices"] == g.n
+    np.testing.assert_array_equal(r.colors,
+                                  greedy_serial(g, "largest_degree_first"))
+
+
+def test_hub_graph_plan_gathers_at_most_three_lanes_per_entry(hub_run):
+    g, r, events = hub_run
+    steps = [e.meta for e in events if e.name == "superstep"]
+    assert steps and all(m["cells"] <= 3 * g.m for m in steps)
+    classes, widths = C._resolve_classes(g.degrees, (), "auto")
+    assert widths[0] == g.max_degree and classes[0].size == 1  # the hub
+    # the colors in play, not the hub's width, set the bitset's words
+    tail = [e.meta for e in events if e.name == "serial_tail"][0]
+    assert max(m["ff_words"] for m in steps) == FF_MIN_WORDS
+    assert tail["ff_words"] == FF_MIN_WORDS and tail["cells"] > g.m
+
+
+def _classes_before(degrees):
+    """The plan before wide classes were split: ``auto_tile_thresholds``'
+    classes, degree-0 vertices in the narrowest, wide first."""
+    dmax = max(int(degrees.max(initial=0)), 1)
+    ts = auto_tile_thresholds(degrees)
+    if not ts:
+        return [np.arange(degrees.size)], [dmax]
+    bounds = list(ts) + [dmax]
+    classes, lo = [], 0
+    for hi in bounds:
+        classes.append(np.where((degrees > lo) & (degrees <= hi))[0])
+        lo = hi
+    classes[0] = np.concatenate([np.where(degrees == 0)[0], classes[0]])
+    pairs = [(c, min(b, dmax)) for c, b in zip(classes, bounds) if c.size]
+    return ([c for c, _ in pairs[::-1]], [w for _, w in pairs[::-1]])
+
+
+@pytest.mark.parametrize("n,seed", [(2**12, 3), (2**14, 3), (2**16, 5)])
+def test_rmat_g_recipe_keeps_its_class_plan_and_tail_shapes(n, seed):
+    """rmat-g's recipe: every automatic class gathers under
+    ``PLAN_LANES_PER_ENTRY`` lanes per entry, so none is split, and the
+    round tail pads each class to the lanes it always did."""
+    g = rmat(n, 10.0, seed=seed)
+    classes, widths = C._resolve_classes(g.degrees, (), "auto")
+    want_c, want_w = _classes_before(g.degrees)
+    assert widths == want_w
+    for got, want in zip(classes, want_c):
+        np.testing.assert_array_equal(got, want)
+    dev = DeviceCSR.from_csr(g)
+    members, tail_widths = C._tail_classes(dev, np.arange(g.n, dtype=np.int32))
+    for m, w in zip(members, tail_widths):
+        for c in (1, 100, 256, 257, m.size):
+            assert C._round_lanes(c, w) == max(C.ROUND_CAP_FLOOR,
+                                               next_pow2(c))
+
+
+@pytest.mark.parametrize("width", [16, 376, 512, 1024, 4096, 162605])
+def test_round_lanes_bound_the_floor_by_cells(width):
+    for count in (1, 3, 200, 5000):
+        lanes = C._round_lanes(count, width)
+        assert lanes >= count and lanes & (lanes - 1) == 0
+        assert lanes * width <= max(C.ROUND_FLOOR_CELLS, width,
+                                    2 * count * width)
+    assert C._round_lanes(1, 162605) == 1
+    assert C._round_lanes(1, 376) == C.ROUND_CAP_FLOOR
+
+
+def test_split_classes_gather_under_two_lanes_per_entry():
+    g = _kron_with_hub(12, 33000)
+    classes, widths = C._resolve_classes(g.degrees, (), "auto")
+    assert sorted(np.concatenate(classes).tolist()) == list(range(g.n))
+    assert widths == sorted(widths, reverse=True)
+    for ids, w in zip(classes, widths):
+        deg = g.degrees[ids]
+        assert deg.max() <= w
+        assert ids.size * w <= max(C.PLAN_LANES_PER_ENTRY * deg.sum(),
+                                   2 * deg.sum(), w)
+
+
+@pytest.mark.parametrize("add", [False, True])
+@pytest.mark.parametrize("n", [64, 200, 257])
+def test_blocked_scatter_matches_one_scatter(monkeypatch, n, add):
+    """Blocks of 64: every index, the sentinel past the end and repeated
+    ones land as one scatter puts them."""
+    monkeypatch.setattr(C, "SCATTER_BLOCK", 64)
+    rng = np.random.default_rng(n)
+    x = jnp.asarray(rng.integers(-5, 5, n).astype(np.int32))
+    idx = jnp.asarray(np.concatenate([rng.permutation(n)[:n // 2],
+                                      [n, n + 1, 0, 0]]).astype(np.int32))
+    vals = jnp.asarray(rng.integers(1, 9, idx.shape[0]).astype(np.int32))
+    if add:
+        want = x.at[idx].add(vals, mode="drop")
+        got = C.blocked_scatter(x, idx, vals, add=True)
+    else:  # a repeated index takes one value: the same one either way
+        vals = vals.at[-1].set(vals[-2])
+        want = x.at[idx].set(vals, mode="drop")
+        got = C.blocked_scatter(x, idx, vals)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_bounded_superstep_firstfit_is_bit_identical():
+    """A hub 700 wide: the workefficient super-steps read the colors back
+    and cap the bitset at 16 of the hub class's 22 words; the fused loop
+    runs the full bitset.  Without the tail the super-steps do all the
+    coloring, and both give the same colors."""
+    from repro.obs.spans import SpanRecorder
+
+    g = _kron_with_hub(10, 700, seed=1)
+    with SpanRecorder() as rec:
+        bounded = color_data_driven(g, tail_serial=None)
+    full = color_data_driven(g, mode="fused", tail_serial=None)
+    np.testing.assert_array_equal(bounded.colors, full.colors)
+    assert bounded.converged and is_valid_coloring(g, bounded.colors)
+    words = [e.meta["ff_words"] for e in rec.events if e.name == "superstep"]
+    assert words and max(words) == FF_MIN_WORDS < (g.max_degree + 32) // 32
 
 
 def _eqn_shapes(jaxpr, out):

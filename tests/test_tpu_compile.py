@@ -27,6 +27,10 @@ RMAT_G_CLASSES = ((45241, 376), (131026, 32), (242471, 16), (629838, 8))
 # to powers of two, at least 16
 RMAT_G_TAIL_CLASSES = ((10, 376), (291, 256), (5801, 128), (39139, 64),
                        (131026, 32), (872309, 16))
+# Graph500 SCALE 22 (kron22): 4,194,304 vertices, 128,310,512 entries, a hub
+# of degree 162,605; its tail has 14 classes
+KRON22_N, KRON22_ENTRIES, KRON22_DMAX, KRON22_TAIL_CLASSES = (
+    4_194_304, 128_310_512, 162_605, 14)
 
 
 @pytest.fixture(scope="module")
@@ -122,11 +126,12 @@ def test_ragged_superstep_compiles_for_v5e_at_rmat_g_size(one_chip):
 
 
 @pytest.mark.parametrize("program", ["setup", "frontier", "round_wide",
-                                     "round_narrow"])
+                                     "round_narrow", "round_hub"])
 def test_round_tail_compiles_for_v5e_at_rmat_g_size(one_chip, program):
     """The round tail's programs as rmat-g's stalled tail runs them: the
     counters along C, the per-class frontier counts, and the rounds of
-    its widest (376 x 256) and largest (16 x 65,536) tiles."""
+    its widest (376 x 256) and largest (16 x 65,536) tiles; and the round
+    of kron22's hub class, one lane 162,605 wide."""
     from repro.core import coloring as C
     from repro.core.csr import DeviceCSR, next_pow2
 
@@ -145,6 +150,17 @@ def test_round_tail_compiles_for_v5e_at_rmat_g_size(one_chip, program):
                    by_entries=True)
     elif program == "frontier":
         hlo = _hlo(C.provider_tail_frontier, vec, vec, vec, classes=K)
+    elif program == "round_hub":
+        # one lane of kron22's hub class, its FirstFit at 16 words of 5,082
+        n, dmax = KRON22_N, KRON22_DMAX
+        provider = DeviceCSR(_spec(one_chip, (n + 1,)),
+                             _spec(one_chip, (KRON22_ENTRIES + dmax,)),
+                             _spec(one_chip, (n + 1,)), n, dmax)
+        vec = _spec(one_chip, (n + 1,))
+        hlo = _hlo(C.provider_tail_round, provider, vec, vec,
+                   _spec(one_chip, (KRON22_TAIL_CLASSES, n)),
+                   _spec(one_chip, ()), cap=C._round_lanes(1, dmax),
+                   width=dmax, kind="bitset", ff_words=16)
     else:
         cap, width = (256, 376) if program == "round_wide" else (65536, 16)
         hlo = _hlo(C.provider_tail_round, provider, vec, vec,
