@@ -28,9 +28,10 @@ RMAT_G_CLASSES = ((45241, 376), (131026, 32), (242471, 16), (629838, 8))
 RMAT_G_TAIL_CLASSES = ((10, 376), (291, 256), (5801, 128), (39139, 64),
                        (131026, 32), (872309, 16))
 # Graph500 SCALE 22 (kron22): 4,194,304 vertices, 128,310,512 entries, a hub
-# of degree 162,605; its tail has 14 classes
+# of degree 162,605; its tail has 13 classes (no degree in (2^12, 2^13] or
+# (2^15, 2^16])
 KRON22_N, KRON22_ENTRIES, KRON22_DMAX, KRON22_TAIL_CLASSES = (
-    4_194_304, 128_310_512, 162_605, 14)
+    4_194_304, 128_310_512, 162_605, 13)
 
 
 @pytest.fixture(scope="module")
